@@ -68,6 +68,7 @@ from repro.core.dispatch import POLICIES
 from repro.core.metrics import DEFAULT_BUCKET_EDGES_T, bucket_stats
 from repro.core.spec import (ExperimentSpec, ServerSpec, TickWorkloadSpec,
                              run_experiment)
+from repro.launch.compile_cache import enable_compile_cache
 
 SHORT_LABEL = f"<{DEFAULT_BUCKET_EDGES_T[0]:g}t"
 SHORT_LABEL_S = "<0.1s"
@@ -201,10 +202,11 @@ def run_fleet1024(n: int) -> list:
     8 lanes rather than 4: doubling lane capacity halves the tick span
     for the same request count, which is what keeps the pair inside the
     invocation's <60 s budget on one core."""
+    from repro.kernels.group_pick import pick_impl
     servers = uniform_servers(1024, 8)
     rows = []
     print(f"tick-engine FLEET1024 (jax backend): engines=1024 lanes=8 "
-          f"load=0.9 n={n}")
+          f"load=0.9 n={n} pick={pick_impl()}")
     for pol in ("sfs-aware", "hash"):
         r = run_tick(pol, servers, 0.9, n=n, seed=11,
                      scenario="fleet1024", backend="jax")
@@ -332,6 +334,7 @@ def main(argv=None):
     ap.add_argument("--n", type=int, default=None, help="requests per run")
     # parse_known_args: tolerate suite names when driven by benchmarks.run
     args, _ = ap.parse_known_args(argv)
+    enable_compile_cache()
 
     if args.trace:
         return run_trace_demo(args.trace, args.n or 10_000)

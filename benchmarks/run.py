@@ -28,6 +28,7 @@ from benchmarks import (cluster_sweep, fig1_duration_cdf, fig2_policies,
                         fig12_overload, predict_sweep, roofline,
                         serving_e2e, table2_overhead)
 from benchmarks.common import OUT_DIR
+from repro.launch.compile_cache import enable_compile_cache
 
 SUITES = {
     "fig1": fig1_duration_cdf,
@@ -132,6 +133,7 @@ def _run_suite(name: str, mod, flags: list) -> int:
 
 def main(argv=None) -> int:
     argv = sys.argv[1:] if argv is None else argv
+    enable_compile_cache()
     flags = [a for a in argv if a.startswith("-")]
     json_mode = "--json" in flags
     flags = [f for f in flags if f != "--json"]

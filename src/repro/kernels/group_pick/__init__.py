@@ -1,2 +1,3 @@
-from repro.kernels.group_pick.ops import (pick_order,  # noqa: F401
-                                          pick_order_argmin, pick_order_ref)
+from repro.kernels.group_pick.ops import (pick_impl,  # noqa: F401
+                                          pick_order, pick_order_argmin,
+                                          pick_order_ref)

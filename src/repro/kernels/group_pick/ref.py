@@ -47,7 +47,9 @@ def pick_order_argmin(vr: jnp.ndarray, rid: jnp.ndarray,
     # positions already picked are excluded via ``avail`` (set to cap),
     # not just by masking vr: sentinel slots are _IMAX already, so a
     # vr-only mask would re-pick the first sentinel forever once the
-    # valid keys run out, where the stable sort keeps advancing
+    # valid keys run out, where the stable sort keeps advancing.  The
+    # winner's rid is masked too, so a picked slot is a full (MAX, MAX)
+    # sentinel and cannot win the sentinel tie on its old rid
     avail = pos
     cols = []
     for _ in range(kmax):
@@ -59,5 +61,6 @@ def pick_order_argmin(vr: jnp.ndarray, rid: jnp.ndarray,
         cols.append(p)
         taken = pos == p[:, None]
         vr = jnp.where(taken, _IMAX, vr)
+        rid = jnp.where(taken, _IMAX, rid)
         avail = jnp.where(taken, cap, avail)
     return jnp.stack(cols, axis=1)

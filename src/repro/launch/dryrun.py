@@ -1,8 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
-                           + os.environ.get("XLA_FLAGS", ""))
-# ^ MUST run before any jax import: jax locks the device count on first init.
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 
@@ -19,10 +14,9 @@ Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch qwen2.5-3b --shape train_4k
   PYTHONPATH=src python -m repro.launch.dryrun --all [--multi-pod] [--force]
 """
-# (no `from __future__ import annotations`: the XLA_FLAGS lines must be the
-#  first statements in the file, which rules out __future__ imports)
 import argparse
 import json
+import os
 import re
 import time
 import traceback
@@ -360,6 +354,11 @@ def artifact_path(arch: str, shape: str, mesh_name: str,
 
 
 def main():
+    # 512 fake host devices for the production meshes; XLA reads the
+    # flag when jax first queries its devices, so it is set here and
+    # never when the module is imported
+    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
+                               + os.environ.get("XLA_FLAGS", ""))
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

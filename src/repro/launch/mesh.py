@@ -6,25 +6,17 @@ device query).
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
 
 
 def make_mesh(shape, axes, *, devices=None):
-    """``jax.make_mesh`` across jax versions.
-
-    ``axis_types`` (and ``jax.sharding.AxisType``) only exist in jax >=
-    0.5; the pinned 0.4.37 predates them and its meshes are implicitly
-    Auto on every axis — which is exactly what we request on newer
-    versions, so both paths build the same mesh.
-    """
+    """``jax.make_mesh`` with every axis ``Auto`` (sharding propagation
+    decides placement, as the partition specs in ``sharding/plan.py``
+    expect)."""
     kw = {} if devices is None else {"devices": devices}
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if (axis_type is not None
-            and "axis_types" in inspect.signature(jax.make_mesh).parameters):
-        kw["axis_types"] = (axis_type.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, **kw)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         **kw)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

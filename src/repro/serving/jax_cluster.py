@@ -32,7 +32,8 @@ per-request device columns.
 The inner fair-share pick (per-group k-smallest ``(vruntime, rid)``)
 goes through :func:`repro.kernels.group_pick.pick_order`, which routes
 to a Pallas kernel on TPU and a sort-free iterative argmin elsewhere
-(XLA:CPU lowers ``sort`` to a scalar comparator loop).
+(XLA:CPU lowers ``sort`` to a scalar comparator loop);
+:func:`repro.kernels.group_pick.pick_impl` names the one compiled in.
 
 **Bit-exactness.**  The step reproduces the vector group's per-tick
 semantics operation for operation, so an ``engine="jax"`` run equals
@@ -47,24 +48,12 @@ decoding, per-server object-engine pinning — pin those runs to the
 """
 from __future__ import annotations
 
-import os
 from collections import deque
 from functools import lru_cache, partial
 from time import perf_counter
 from typing import Optional, Sequence
 
 import numpy as np
-
-# XLA:CPU's thunk runtime roughly doubles the per-dispatch cost of the
-# many small kernels a 1024-engine tick compiles to; the legacy runtime
-# halves the measured step time.  Only effective if no jax backend has
-# been initialized yet, hence set at import — callers that already set
-# the flag (either way) win.
-_flags = os.environ.get("XLA_FLAGS", "")
-if "xla_cpu_use_thunk_runtime" not in _flags:
-    os.environ["XLA_FLAGS"] = (
-        _flags + " --xla_cpu_use_thunk_runtime=false").strip()
-del _flags
 
 from repro.core.dispatch import (BoundedTimeline, ServerStateColumns,
                                  ServerView)
@@ -106,6 +95,29 @@ def _scan_evcap(G: int, L: int, sfs: bool) -> int:
 
 _STATE_KEYS = ("q", "qh", "qn", "lanes", "lc", "pool", "pc", "minvr",
                "last")
+
+
+def state_shapes(G: int, L: int, QCAP: int, CAP: int) -> dict:
+    """Shapes of one group's int32 device state, keyed like
+    ``_STATE_KEYS``."""
+    return dict(q=(G, QCAP, _NQ), qh=(G,), qn=(G,), lanes=(G, L, _NL),
+                lc=(G,), pool=(G, CAP, _NP), pc=(G,), minvr=(G,),
+                last=(G, L))
+
+
+def step_arg_specs(G: int, L: int, QCAP: int, CAP: int, ACAP: int,
+                   sharding=None) -> tuple:
+    """Shape/dtype structs of ``(state, arr, t, S, thr)``, the
+    arguments of the jitted group step, for compiling it without arrays
+    (on a described device, or to inspect the compiled program)."""
+    import jax
+    import jax.numpy as jnp
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
+
+    state = {k: spec(*s) for k, s in state_shapes(G, L, QCAP, CAP).items()}
+    return state, spec(ACAP, _NA), spec(), spec(G), spec(G)
 
 
 def _tick_core(G, L, QCAP, CAP, sfs, evcap, trace, state, arr, t, S, thr):
@@ -477,12 +489,9 @@ class _JaxGroup:
     # -- device plumbing ----------------------------------------------
     def _fresh_state(self):
         import jax.numpy as jnp
-        G, L = self.G, self.lanes
-        z = lambda *s: jnp.zeros(s, jnp.int32)  # noqa: E731
-        return dict(q=z(G, self.QCAP, _NQ), qh=z(G), qn=z(G),
-                    lanes=z(G, L, _NL), lc=z(G),
-                    pool=z(G, self.CAP, _NP), pc=z(G), minvr=z(G),
-                    last=jnp.full((G, L), -1, jnp.int32))
+        shapes = state_shapes(self.G, self.lanes, self.QCAP, self.CAP)
+        return {k: jnp.full(s, -1 if k == "last" else 0, jnp.int32)
+                for k, s in shapes.items()}
 
     def _compile(self):
         self._step_fn, self._scan_fn, self._adv_fn = _build_fns(

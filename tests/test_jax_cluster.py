@@ -252,15 +252,15 @@ def test_region_growth_under_single_tick_flood():
 # ---------------------------------------------------------------------------
 
 
-def _pick_cases():
+def _pick_cases(G=8, CAP=16, seed=3):
     import jax.numpy as jnp
     from repro.kernels.group_pick.ref import _IMAX
-    rng = np.random.default_rng(3)
-    G, CAP = 8, 16
+    rng = np.random.default_rng(seed)
     # heavy vruntime ties + unique rids, ~30% sentinel slots
     vr = rng.integers(0, 6, (G, CAP)).astype(np.int32)
     rid = rng.permutation(G * CAP).reshape(G, CAP).astype(np.int32)
     hole = rng.random((G, CAP)) < 0.3
+    hole[1:3, 3:] = True        # pools with fewer valid keys than kmax
     vr = np.where(hole, _IMAX, vr)
     rid = np.where(hole, _IMAX, rid)
     vr[0, :] = _IMAX            # one fully-empty pool
@@ -270,11 +270,30 @@ def _pick_cases():
 
 def test_pick_order_argmin_matches_ref():
     from repro.kernels.group_pick import pick_order_argmin, pick_order_ref
-    vr, rid = _pick_cases()
-    for kmax in (1, 4, 8):
-        ref = np.asarray(pick_order_ref(vr, rid, kmax))
-        got = np.asarray(pick_order_argmin(vr, rid, kmax))
-        assert (ref == got).all(), kmax
+    for G in (8, 12):
+        vr, rid = _pick_cases(G)
+        for kmax in (1, 4, 8):
+            ref = np.asarray(pick_order_ref(vr, rid, kmax))
+            got = np.asarray(pick_order_argmin(vr, rid, kmax))
+            assert (ref == got).all(), (G, kmax)
+
+
+def test_pick_order_keeps_stable_sort_order_after_valid_keys_run_out():
+    """Once a pool's valid keys are picked, the remaining picks are its
+    sentinel slots in position order, as the stable sort gives them —
+    a picked slot keeps no rid that could win the sentinel tie."""
+    import jax.numpy as jnp
+
+    from repro.kernels.group_pick import pick_order_argmin, pick_order_ref
+    from repro.kernels.group_pick.kernel import pick_order_pallas
+    from repro.kernels.group_pick.ref import _IMAX
+    vr = jnp.asarray([[5, _IMAX, 3, _IMAX]], jnp.int32)
+    rid = jnp.asarray([[7, _IMAX, 2, _IMAX]], jnp.int32)
+    want = [[2, 0, 1, 3]]
+    assert np.asarray(pick_order_ref(vr, rid, 4)).tolist() == want
+    assert np.asarray(pick_order_argmin(vr, rid, 4)).tolist() == want
+    assert np.asarray(pick_order_pallas(vr, rid, 4,
+                                        interpret=True)).tolist() == want
 
 
 def test_pick_order_pallas_interpret_matches_ref():
@@ -288,12 +307,31 @@ def test_pick_order_pallas_interpret_matches_ref():
         assert (ref == got).all(), (kmax, gb)
 
 
+@pytest.mark.parametrize("G,gb", [(12, 8), (12, 3), (1, 8), (20, 16),
+                                  (13, 1)])
+def test_pick_order_pallas_interpret_pads_ragged_groups(G, gb):
+    """G not a multiple of the 8-row tile: the kernel pads with
+    sentinel rows (any requested ``gb`` rounds up to the tile) and
+    slices them off."""
+    from repro.kernels.group_pick.kernel import pick_order_pallas
+    from repro.kernels.group_pick.ref import pick_order_ref
+    vr, rid = _pick_cases(G, seed=G)
+    for kmax in (1, 4, 8):
+        ref = np.asarray(pick_order_ref(vr, rid, kmax))
+        got = np.asarray(pick_order_pallas(vr, rid, kmax, gb=gb,
+                                           interpret=True))
+        assert got.shape == (G, kmax)
+        assert (ref == got).all(), (G, gb, kmax)
+
+
 def test_pick_order_dispatcher_off_tpu():
     import jax
 
-    from repro.kernels.group_pick import pick_order, pick_order_ref
+    from repro.kernels.group_pick import (pick_impl, pick_order,
+                                         pick_order_ref)
     if jax.default_backend() == "tpu":
         pytest.skip("dispatcher routes to the Pallas kernel on TPU")
     vr, rid = _pick_cases()
+    assert pick_impl() == "argmin"
     assert (np.asarray(pick_order(vr, rid, 4))
             == np.asarray(pick_order_ref(vr, rid, 4))).all()
