@@ -1243,11 +1243,16 @@ def _run_tick(spec: ExperimentSpec, requests, t0: float,
                 f"workload (or an explicit request list); got "
                 f"{spec.workload!r}")
         requests = spec.workload.generate(spec.total_cores)
+    prof = tel.profile if tel is not None else None
+    if prof is not None:
+        prof.begin("build")
     cluster = _build_tick_cluster(spec)
     if tel is not None:
         cluster.attach_telemetry(tel)
+    if prof is not None:
+        prof.end("build")
     done = cluster.run(requests, max_ticks=max_ticks)
-    return ExperimentResult(
+    res = ExperimentResult(
         spec=spec, engine=spec.engine, unit="t",
         rids=np.array([r.rid for r in done]),
         service=np.array([r.service_demand for r in done],
@@ -1265,3 +1270,6 @@ def _run_tick(spec: ExperimentSpec, requests, t0: float,
         dispatch_S=getattr(cluster.policy, "S", None),
         wall_s=time.perf_counter() - t0, raw=done, telemetry=tel,
         **_chaos_counts(cluster))
+    if prof is not None:
+        prof.end("result")          # opened by cluster.run at its loop's exit
+    return res

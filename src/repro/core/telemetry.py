@@ -26,6 +26,7 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import json
+import sys
 import time
 from typing import Optional
 
@@ -233,26 +234,47 @@ class FleetSeries:
 # ---------------------------------------------------------------------------
 
 
+def _annotation(name: str):
+    """An entered ``jax.profiler.TraceAnnotation`` named ``repro.<name>``
+    while a jax profiler session records, else None.  jax is never
+    imported here: with no jax in ``sys.modules`` no session can be
+    live, so the DES and the numpy backends never need it."""
+    jax = sys.modules.get("jax")
+    if jax is None or not jax.profiler.TraceAnnotation.is_enabled():
+        return None
+    ann = jax.profiler.TraceAnnotation("repro." + name)
+    ann.__enter__()
+    return ann
+
+
 class HostProfile:
     """Wall-clock accumulator for named host-loop phases.
 
-    Usage at a call site (guarded, so the disabled path costs one
-    attribute read)::
+    A phase is a span opened and closed at a call site, guarded so the
+    disabled path costs one attribute read::
 
         prof = self.prof
-        t0 = time.perf_counter() if prof is not None else 0.0
+        if prof is not None:
+            prof.begin("jax_step")
         ...phase work...
         if prof is not None:
-            prof.add("jax_step", time.perf_counter() - t0)
+            prof.end("jax_step")
+
+    Spans nest: ``end`` closes the innermost open span and refuses any
+    other name.  While a jax profiler session records, each span is also
+    a ``repro.<phase>`` annotation on the profiler's host timeline, the
+    clock its device events share.  Totals accumulate as
+    ``phases[name] = [seconds, calls]``.
 
     Phase names are a flat namespace; docs/OBSERVABILITY.md carries the
-    glossary (route, step, jax_step, jax_events, jax_scan, ...).
+    glossary (build, route, step, jax_step, jax_sync, jax_scan, ...).
     """
 
-    __slots__ = ("phases",)
+    __slots__ = ("phases", "_open")
 
     def __init__(self):
         self.phases: dict = {}          # name -> [total_s, count]
+        self._open: list = []   # (name, annotation, start), innermost last
 
     def add(self, name: str, dt: float):
         slot = self.phases.get(name)
@@ -262,8 +284,17 @@ class HostProfile:
             slot[0] += dt
             slot[1] += 1
 
-    def timer(self):
-        return time.perf_counter()
+    def begin(self, name: str):
+        self._open.append((name, _annotation(name), time.perf_counter()))
+
+    def end(self, name: str):
+        t1 = time.perf_counter()
+        top, ann, t0 = self._open.pop()
+        if top != name:
+            raise ValueError(f"span {name!r} closed while {top!r} is open")
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        self.add(name, t1 - t0)
 
     def summary(self) -> dict:
         return {name: {"total_s": round(tot, 6), "calls": n,

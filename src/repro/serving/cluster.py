@@ -27,7 +27,6 @@ from __future__ import annotations
 
 import dataclasses
 from collections import deque
-from time import perf_counter
 from typing import Optional, Sequence
 
 import numpy as np
@@ -176,7 +175,8 @@ class ClusterFrontend:
             self._active = self._scaler.initial_active()
             self.policy.set_active(self._active)
         # (t, central_qlen after pulls, tuple of per-engine active counts)
-        self.tick_log: list[tuple[int, int, tuple]] = []
+        # per tick; off (None) unless a caller sets a list before run()
+        self.tick_log: Optional[list[tuple[int, int, tuple]]] = None
         # opt-in telemetry (core/telemetry.py): all None when disabled,
         # so the hot loop pays one attribute read per guard and nothing
         # else (pinned by tests/test_telemetry.py)
@@ -496,7 +496,8 @@ class ClusterFrontend:
         if (arrivals and self._watchdog is not None
                 and self._watchdog.shed is not None):
             arrivals = self._shed_filter(arrivals)
-        t0 = perf_counter() if prof is not None else 0.0
+        if prof is not None:
+            prof.begin("route")
         if isinstance(self.policy, HashDispatch):
             # legacy Router semantics: route the whole tick's batch
             # against pre-delivery state (p2c comparisons unaffected by
@@ -522,13 +523,14 @@ class ClusterFrontend:
                     break
                 self._deliver(idx, self.central_queue.popleft())
         if prof is not None:
-            prof.add("route", perf_counter() - t0)
-            t0 = perf_counter()
+            prof.end("route")
+            prof.begin("step")
         self._step()
         if prof is not None:
-            prof.add("step", perf_counter() - t0)
-        self.tick_log.append(
-            (self.t, len(self.central_queue), self._active_counts()))
+            prof.end("step")
+        if self.tick_log is not None:
+            self.tick_log.append(
+                (self.t, len(self.central_queue), self._active_counts()))
         ser = self._series
         if ser is not None and self.t % ser.cadence == 0:
             ser.sample(self.t, self.views,
@@ -537,8 +539,16 @@ class ClusterFrontend:
 
     def run(self, workload: Sequence[Request], max_ticks: int = 1_000_000,
             prompts: Optional[dict] = None) -> list[Request]:
-        """Drive the cluster over a workload; returns requests rid-sorted."""
+        """Drive the cluster over a workload; returns requests rid-sorted.
+        With a profile attached, ``intake`` spans the sort of the
+        workload and ``result`` opens at the loop's exit, for
+        ``run_experiment`` to close once its result is built."""
+        prof = self._prof
+        if prof is not None:
+            prof.begin("intake")
         workload = sorted(workload, key=lambda r: r.arrival)
+        if prof is not None:
+            prof.end("intake")
         i, n = 0, len(workload)
         # shed requests never finish; they terminate the loop as their
         # own accounting, excluded from every completion metric
@@ -555,6 +565,8 @@ class ClusterFrontend:
                 arrivals.append(r)
                 i += 1
             self.tick(arrivals)
+        if prof is not None:
+            prof.begin("result")
         return sorted(self._collect(), key=lambda r: r.rid)
 
     # ------------------------------------------------------------------

@@ -50,7 +50,6 @@ from __future__ import annotations
 
 from collections import deque
 from functools import lru_cache, partial
-from time import perf_counter
 from typing import Optional, Sequence
 
 import numpy as np
@@ -511,6 +510,9 @@ class _JaxGroup:
         """Resize a device region: pull, pad (unrolling the queue ring
         to head 0), push back, re-jit against the new shapes."""
         import jax.numpy as jnp
+        prof = self.prof
+        if prof is not None:
+            prof.begin("jax_grow")
         host = {k: np.asarray(v) for k, v in self._state.items()}
         if qcap is not None and qcap > self.QCAP:
             q2 = np.zeros((self.G, qcap, _NQ), np.int32)
@@ -527,6 +529,8 @@ class _JaxGroup:
             self.CAP = cap
         self._state = {k: jnp.asarray(v) for k, v in host.items()}
         self._compile()
+        if prof is not None:
+            prof.end("jax_grow")
 
     # -- arrivals (host-classified, device-scattered) ------------------
     def _observe_iat(self, j: int, t: int):
@@ -599,6 +603,9 @@ class _JaxGroup:
             np.ceil(self.overload_factor * self.S), _IMAX).astype(np.int32)
 
     def step_tick(self, t: int) -> list:
+        prof = self.prof
+        if prof is not None:
+            prof.begin("jax_prep")
         self._admit_pending(t)
         batch, self._batch = self._batch, []
         G, L = self.G, self.lanes
@@ -640,14 +647,19 @@ class _JaxGroup:
             arr[:len(b), :5] = b
             arr[:len(b), 5] = pos
         qn_in = self.qlen.copy()
-        prof = self.prof
-        pt = perf_counter() if prof is not None else 0.0
+        if prof is not None:
+            prof.end("jax_prep")
+            prof.begin("jax_step")
         state, out = self._step_fn(
             self._state, arr, np.int32(t),
             self.S.astype(np.int32), self._thr32())
         self._state = state
+        if prof is not None:
+            prof.begin("jax_sync")
         scal = np.asarray(out["scal"])
         mir = np.asarray(out["mirrors"]).astype(np.int64)
+        if prof is not None:
+            prof.end("jax_sync")
         n_ev = int(scal[0])
         self.min_next = int(scal[1])
         qn2, lc2, pc2, nact, nbyp = mir
@@ -660,7 +672,7 @@ class _JaxGroup:
         self.lane_busy_ticks += nact
         self.overload_bypasses += nbyp
         if prof is not None:
-            prof.add("jax_step", perf_counter() - pt)
+            prof.end("jax_step")
         if self.trace is not None:
             self._emit_trace(out, t)
         if n_ev == 0:
@@ -668,11 +680,12 @@ class _JaxGroup:
         # pull the whole buffer and slice on the host: a device-side
         # ``[:n_ev]`` is an un-jitted slice whose output shape changes
         # every tick, so XLA would recompile it per distinct n_ev
-        pt = perf_counter() if prof is not None else 0.0
+        if prof is not None:
+            prof.begin("jax_events")
         ev = np.asarray(out["events"])[:n_ev].astype(np.int64)
         res = self._process_events(ev, t)
         if prof is not None:
-            prof.add("jax_events", perf_counter() - pt)
+            prof.end("jax_events")
         return res
 
     def _emit_trace(self, out, t: int):
@@ -1077,6 +1090,9 @@ class JaxCluster(ClusterFrontend):
     def _replay(self, events: list, t: int):
         """Merge per-group completion tuples into object-cluster order
         and drive the predictor feedback loop."""
+        prof = self._prof
+        if prof is not None:
+            prof.begin("replay")
         events.sort(key=lambda e: (e[0], e[1]))
         ser = self._series
         st = self.store
@@ -1089,6 +1105,8 @@ class JaxCluster(ClusterFrontend):
                     c["demoted_done"] += 1
                 c["nctx_done"] += int(st.n_ctx[row])
             self._observe_finish(st.reqs[row], t + 1)
+        if prof is not None:
+            prof.end("replay")
 
     def _step(self):
         events = []
@@ -1098,10 +1116,15 @@ class JaxCluster(ClusterFrontend):
         self._cols.mark_all()
 
     def _active_counts(self) -> tuple:
+        return self._per_server([g.n_active for g in self.groups])
+
+    def _per_server(self, per_group) -> tuple:
+        """One value per server from one array per group (member order),
+        for the tick log."""
         counts = [0] * self.n_servers
-        for group in self.groups:
+        for group, vals in zip(self.groups, per_group):
             for j, idx in enumerate(group.members):
-                counts[idx] = int(group.n_active[j])
+                counts[idx] = int(vals[j])
         return tuple(counts)
 
     def _finished_count(self) -> int:
@@ -1109,21 +1132,14 @@ class JaxCluster(ClusterFrontend):
 
     def _collect(self) -> list:
         prof = self._prof
-        pt = perf_counter() if prof is not None else 0.0
+        if prof is not None:
+            prof.begin("jax_writeback")
         out = self.store.write_back_many(self._done_rows)
         if prof is not None:
-            prof.add("jax_writeback", perf_counter() - pt)
+            prof.end("jax_writeback")
         return out
 
     # -- event-driven multi-tick batching ------------------------------
-    def _gap_counts(self) -> tuple:
-        counts = [0] * self.n_servers
-        for group in self.groups:
-            nact = group.gap_active_counts()
-            for j, idx in enumerate(group.members):
-                counts[idx] = int(nact[j])
-        return tuple(counts)
-
     def _fast_forward(self, window: int) -> bool:
         """Advance up to ``window`` arrival-free ticks without paying
         per-tick dispatch: a closed-form gap jump when no event can
@@ -1136,22 +1152,27 @@ class JaxCluster(ClusterFrontend):
             # the gap advance is trace-safe: no event of any kind can
             # occur inside the gap, so there is nothing to emit
             prof = self._prof
-            pt = perf_counter() if prof is not None else 0.0
-            counts = self._gap_counts()
+            if prof is not None:
+                prof.begin("jax_advance")
+            log = self.tick_log
+            if log is not None:
+                counts = self._per_server(
+                    [g.gap_active_counts() for g in self.groups])
+                log.extend((self.t + dt, 0, counts) for dt in range(gap))
             for group in self.groups:
                 group.advance(gap, self.t)
             ser = self._series
-            for dt in range(gap):
-                self.tick_log.append((self.t + dt, 0, counts))
-                if ser is not None and (self.t + dt) % ser.cadence == 0:
-                    # gauges are frozen across an event-free gap, so the
-                    # live views sample the exact per-tick values
-                    ser.sample(self.t + dt, self.views,
-                               {"central_queue": len(self.central_queue)})
+            if ser is not None:
+                for dt in range(gap):
+                    if (self.t + dt) % ser.cadence == 0:
+                        # gauges are frozen across an event-free gap, so
+                        # the live views sample the exact per-tick values
+                        ser.sample(self.t + dt, self.views,
+                                   {"central_queue": len(self.central_queue)})
             self.t += gap
             self._cols.mark_all()
             if prof is not None:
-                prof.add("jax_advance", perf_counter() - pt)
+                prof.end("jax_advance")
             return True
         # scan chunks skip the per-tick host loop, so they cannot emit
         # trace events or series samples — fall back to per-tick
@@ -1165,7 +1186,8 @@ class JaxCluster(ClusterFrontend):
     def _scan_window(self) -> bool:
         t0 = self.t
         prof = self._prof
-        pt = perf_counter() if prof is not None else 0.0
+        if prof is not None:
+            prof.begin("jax_scan")
         payloads = []
         for group in self.groups:
             ok, res = group.scan(t0)
@@ -1175,33 +1197,38 @@ class JaxCluster(ClusterFrontend):
                 # per-tick path has stepped past the burst tick
                 self._scan_cooldown = t0 + res + 1
                 if prof is not None:
-                    prof.add("jax_scan", perf_counter() - pt)
+                    prof.end("jax_scan")
                 return False
             payloads.append(res)
         if prof is not None:
-            prof.add("jax_scan", perf_counter() - pt)
-            pt = perf_counter()
+            prof.end("jax_scan")
+            prof.begin("jax_commit")
         per_group = [g.commit_scan(t0, p)
                      for g, p in zip(self.groups, payloads)]
+        log = self.tick_log
         for i in range(_SCAN_CHUNK):
             t = t0 + i
             events = []
-            counts = [0] * self.n_servers
-            for group, (per_tick, nacts) in zip(self.groups, per_group):
+            for per_tick, _ in per_group:
                 events.extend(per_tick[i])
-                for j, idx in enumerate(group.members):
-                    counts[idx] = int(nacts[i][j])
             self._replay(events, t)
-            self.tick_log.append((t, 0, tuple(counts)))
+            if log is not None:
+                log.append((t, 0, self._per_server(
+                    [nacts[i] for _, nacts in per_group])))
         self.t = t0 + _SCAN_CHUNK
         self._cols.mark_all()
         if prof is not None:
-            prof.add("jax_commit", perf_counter() - pt)
+            prof.end("jax_commit")
         return True
 
     def run(self, workload: Sequence[Request], max_ticks: int = 1_000_000,
             prompts: Optional[dict] = None) -> list[Request]:
+        prof = self._prof
+        if prof is not None:
+            prof.begin("intake")
         workload = sorted(workload, key=lambda r: r.arrival)
+        if prof is not None:
+            prof.end("intake")
         i, n = 0, len(workload)
         # shed requests never finish; they terminate the loop as their
         # own accounting, excluded from every completion metric
@@ -1230,6 +1257,8 @@ class JaxCluster(ClusterFrontend):
                 if self._fast_forward(limit - self.t):
                     continue
             self.tick(arrivals)
+        if prof is not None:
+            prof.begin("result")        # closed by run_experiment
         return sorted(self._collect(), key=lambda r: r.rid)
 
     # ------------------------------------------------------------------

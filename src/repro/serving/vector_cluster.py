@@ -39,7 +39,6 @@ decoding — the vector backend is the synthetic scheduling mode only.
 from __future__ import annotations
 
 from collections import deque
-from time import perf_counter
 from typing import Optional, Sequence
 
 import numpy as np
@@ -739,7 +738,8 @@ class VectorCluster(ClusterFrontend):
 
     def _step(self):
         prof = self._prof
-        t0 = perf_counter() if prof is not None else 0.0
+        if prof is not None:
+            prof.begin("group_step")
         events = []
         self._straggler_obs = []
         for idx, e in self.stragglers.items():
@@ -748,8 +748,8 @@ class VectorCluster(ClusterFrontend):
         for group in self.groups:
             events.extend(group.tick(self.t))
         if prof is not None:
-            prof.add("group_step", perf_counter() - t0)
-            t0 = perf_counter()
+            prof.end("group_step")
+            prof.begin("replay")
         # replay completions in object-cluster order: server index
         # ascending, then each engine's chosen order — so learned
         # predictors see the exact same observation stream
@@ -760,7 +760,7 @@ class VectorCluster(ClusterFrontend):
             self._observe_finish(req, self.t + 1)
         self._cols.mark_all()
         if prof is not None:
-            prof.add("replay", perf_counter() - t0)
+            prof.end("replay")
 
     def _active_counts(self) -> tuple:
         counts = [0] * self.n_servers

@@ -68,8 +68,10 @@ def test_pull_work_conservation(seed, n_engines, lanes):
     non-empty (slots are ample and the workload never stalls, so an
     engine that runs < lanes requests could have pulled)."""
     cluster = make_cluster("pull", n_engines, lanes=lanes, n_slots=128)
+    cluster.tick_log = []
     cluster.run(workload(n=40, lanes=lanes * n_engines, seed=seed),
                 max_ticks=2_000_000)
+    assert len(cluster.tick_log) == cluster.t
     for t, central_qlen, actives in cluster.tick_log:
         if central_qlen > 0:
             assert all(a == lanes for a in actives), \

@@ -121,8 +121,10 @@ def test_fast_paths_match_per_tick_stepping(policy):
             return took
 
     fast = Spy(specs, ClusterConfig(policy=policy))
+    fast.tick_log = []
     got = fast.run(_sparse_workload(), max_ticks=200_000)
     ref = JaxCluster(specs, ClusterConfig(policy=policy))
+    ref.tick_log = []
     want = per_tick_run(ref, _sparse_workload())
     assert any(fired), "sparse workload never engaged a fast path"
     assert fingerprint(got) == fingerprint(want)
@@ -133,6 +135,23 @@ def test_fast_paths_match_per_tick_stepping(policy):
     assert fast.t - ref.t < _SCAN_CHUNK
     assert fast.tick_log[:n] == ref.tick_log
     assert all(c == (0,) * len(specs) for _, _, c in fast.tick_log[n:])
+
+
+@pytest.mark.parametrize("cls", [JaxCluster, VectorCluster])
+def test_tick_log_is_opt_in(cls):
+    """No tick log unless a caller sets a list before run(); keeping one
+    changes no result, and the gap advance and scan chunks log every
+    tick they carry."""
+    specs = [ServerSpec(cores=2)] * 3
+    for wl in (_sparse_workload, _burst_workload):
+        off = cls(specs, ClusterConfig(policy="least-outstanding"))
+        got = off.run(wl(), max_ticks=200_000)
+        assert off.tick_log is None
+        on = cls(specs, ClusterConfig(policy="least-outstanding"))
+        on.tick_log = []
+        want = on.run(wl(), max_ticks=200_000)
+        assert fingerprint(got) == fingerprint(want)
+        assert [t for t, _, _ in on.tick_log] == list(range(on.t))
 
 
 def test_gap_advance_skips_pure_drain():

@@ -8,6 +8,7 @@ import tracemalloc
 import numpy as np
 import pytest
 
+from repro.core import telemetry
 from repro.core.metrics import cdf, percentiles
 from repro.core.spec import (ExperimentSpec, ServerSpec, TickWorkloadSpec,
                              run_experiment)
@@ -112,6 +113,31 @@ def test_host_profile_accumulates_and_formats():
     assert "step" in prof.format() and "%" in prof.format()
 
 
+def test_host_profile_spans_nest_and_total():
+    prof = HostProfile()
+    prof.begin("step")
+    for _ in range(3):
+        prof.begin("jax_step")
+        prof.begin("jax_sync")
+        prof.end("jax_sync")
+        prof.end("jax_step")
+    prof.end("step")
+    assert {k: v[1] for k, v in prof.phases.items()} == {
+        "step": 1, "jax_step": 3, "jax_sync": 3}
+    assert prof.phases["step"][0] >= prof.phases["jax_step"][0] \
+        >= prof.phases["jax_sync"][0] >= 0
+    prof.begin("route")
+    prof.begin("replay")
+    with pytest.raises(ValueError):
+        prof.end("route")           # only the innermost span may close
+
+
+def test_spans_write_no_annotation_without_a_profiler_session():
+    """With no jax profiler session recording, a span is host-clock
+    bookkeeping only."""
+    assert telemetry._annotation("route") is None
+
+
 def test_telemetry_ensure_normalizes():
     assert Telemetry.ensure(None) is None
     tel = Telemetry(trace=True)
@@ -185,20 +211,99 @@ def test_enabling_telemetry_keeps_fingerprints_bit_exact(engine):
         assert tel.profile.phases
 
 
+def _telemetry_allocations(engine):
+    """Bytes tracemalloc attributes to telemetry.py over one run with
+    telemetry off (caches warmed by a first run)."""
+    run_experiment(_spec(engine), max_ticks=2_000_000)
+    tracemalloc.start()
+    res = run_experiment(_spec(engine), max_ticks=2_000_000)
+    snap = tracemalloc.take_snapshot()
+    tracemalloc.stop()
+    leaked = [s for s in snap.statistics("filename")
+              if s.traceback[0].filename == telemetry.__file__]
+    assert res.telemetry is None
+    return leaked
+
+
 def test_disabled_telemetry_adds_zero_allocations_to_vector_step():
     """With telemetry off, the hot loop must never touch telemetry.py:
     every emission site is a single `is not None` attribute check, so
     tracemalloc attributes zero allocations to the module."""
-    import repro.core.telemetry as tmod
-    run_experiment(_spec("vector"), max_ticks=2_000_000)   # warm caches
-    tracemalloc.start()
-    res = run_experiment(_spec("vector"), max_ticks=2_000_000)
-    snap = tracemalloc.take_snapshot()
-    tracemalloc.stop()
-    leaked = [s for s in snap.statistics("filename")
-              if s.traceback[0].filename == tmod.__file__]
-    assert res.telemetry is None
+    leaked = _telemetry_allocations("vector")
     assert sum(s.size for s in leaked) == 0, leaked
+
+
+def test_disabled_telemetry_adds_zero_allocations_to_jax_step():
+    """The same on the jitted backend: no span is opened, so no
+    ``repro.*`` annotation is created and nothing is allocated."""
+    leaked = _telemetry_allocations("jax")
+    assert sum(s.size for s in leaked) == 0, leaked
+
+
+#: the spans that tile one jax experiment
+TOP_SPANS = ("build", "intake", "route", "step", "jax_advance", "jax_scan",
+             "jax_commit", "result")
+
+
+def _jax_fleet(n=3000):
+    servers = tuple(ServerSpec(cores=2) for _ in range(16))
+    return ExperimentSpec(engine="jax", servers=servers,
+                          dispatch="sfs-aware",
+                          workload=TickWorkloadSpec(n=n, load=0.9, seed=5))
+
+
+def test_jax_profile_spans_every_phase_once_or_per_step():
+    """A profile-only jax run: the experiment-level spans once each,
+    one prep and one device wait per jitted step, the replay recorded,
+    and results bit-identical to an untraced run."""
+    base = run_experiment(_jax_fleet())
+    tel = Telemetry(profile=True)
+    res = run_experiment(_jax_fleet(), telemetry=tel)
+    assert res.fingerprint() == base.fingerprint()
+    ph = tel.profile.phases
+    assert {k: ph[k][1] for k in ("build", "intake", "result")} == {
+        "build": 1, "intake": 1, "result": 1}
+    assert ph["jax_prep"][1] == ph["jax_sync"][1] == ph["jax_step"][1] \
+        == ph["step"][1]
+    assert ph["replay"][1] >= ph["step"][1]
+    assert ph["jax_writeback"][1] == 1
+    # every span closed; the experiment-level ones tile the wall time,
+    # so together they cannot exceed it
+    assert not tel.profile._open
+    assert sum(ph[k][0] for k in TOP_SPANS if k in ph) <= res.wall_s
+    for inner, outer in (("jax_sync", "jax_step"), ("jax_step", "step"),
+                         ("jax_prep", "step"), ("jax_writeback", "result")):
+        assert ph[inner][0] <= ph[outer][0]
+
+
+def test_spans_are_profiler_annotations_on_the_host_timeline(tmp_path):
+    """Under a live jax profiler session every span is also a
+    ``repro.<phase>`` host event, nested as the spans nest."""
+    import jax
+    run_experiment(_jax_fleet(600))                 # compile off the trace
+    tel = Telemetry(profile=True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        run_experiment(_jax_fleet(600), telemetry=tel)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    spans = [(e.start_ns, e.start_ns + e.duration_ns, e.name[len("repro."):])
+             for p in data.planes if p.name.startswith("/host:")
+             for line in p.lines for e in line.events
+             if e.name.startswith("repro.")]
+    counts = {}
+    for _, _, n in spans:
+        counts[n] = counts.get(n, 0) + 1
+    assert counts == {k: v[1] for k, v in tel.profile.phases.items()}
+
+    def inside(inner, outer):
+        outs = [(s, e) for s, e, n in spans if n == outer]
+        return all(any(s0 <= s and e <= e0 for s0, e0 in outs)
+                   for s, e, n in spans if n == inner)
+    assert inside("jax_sync", "jax_step") and inside("jax_prep", "step")
+    assert inside("jax_writeback", "result")
 
 
 # ---------------------------------------------------------------------------

@@ -64,9 +64,10 @@ def test_empty_ticks_are_inert():
     """Ticking an idle vector cluster advances time and nothing else —
     and the cluster still serves correctly afterwards."""
     vc = make_vc([ServerSpec(cores=2, slots=8)] * 3)
+    vc.tick_log = []
     for _ in range(50):
         vc.tick(())
-    assert vc.t == 50
+    assert vc.t == 50 and len(vc.tick_log) == 50
     assert vc._finished_count() == 0
     assert all(qlen == 0 and actives == (0, 0, 0)
                for _, qlen, actives in vc.tick_log)
