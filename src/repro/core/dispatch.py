@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import hashlib
 from collections import deque
+from itertools import repeat
 from typing import Optional, Sequence
 
 import numpy as np
@@ -86,6 +87,40 @@ class BoundedTimeline:
 
     def __repr__(self):
         return f"BoundedTimeline({self._data!r}, cap={self.cap})"
+
+
+def observe_instant(iats: deque, window: int, since: int,
+                    first_iat: Optional[int], m: int):
+    """``m`` arrivals at one instant through an adaptive-slice IAT
+    window, as ``m`` successive per-arrival observations would: the
+    first appends ``first_iat`` (None: no earlier arrival, nothing
+    appended), the rest append 0, and the slice is recomputed whenever
+    ``since`` reaches ``window`` over a full window.
+
+    Mutates ``iats`` (a ``deque(maxlen=window)``); returns the new
+    ``since`` and ``[(k, total), ...]``: the arrival index at which each
+    recomputation fires and the window's integer sum there (its mean is
+    ``total / window``)."""
+    fires = []
+    if first_iat is not None:
+        iats.append(first_iat)
+    since += 1
+    if since >= window and len(iats) == window:
+        fires.append((0, sum(iats)))
+        since = 0
+    k = 1
+    while k < m:
+        # zero-IAT arrivals until both the count and the window are full
+        s = max(window - since, window - len(iats), 1)
+        if k + s > m:
+            iats.extend(repeat(0, min(m - k, window)))
+            since += m - k
+            break
+        iats.extend(repeat(0, min(s, window)))
+        k += s
+        fires.append((k - 1, sum(iats)))
+        since = 0
+    return since, fires
 
 
 class ServerView:
@@ -247,6 +282,16 @@ def _hash(rid: int, salt: int) -> int:
     return int.from_bytes(h.digest(), "little")
 
 
+def _hash_many(rids: Sequence[int], salt: int) -> np.ndarray:
+    """``_hash(rid, salt)`` for every rid: the same digests, read as
+    one little-endian uint32 buffer."""
+    tail = b":%d" % salt
+    b2s = hashlib.blake2s
+    return np.frombuffer(
+        b"".join([b2s(b"%d%s" % (r, tail), digest_size=4).digest()
+                  for r in rids]), "<u4").astype(np.int64)
+
+
 @DISPATCH_REGISTRY.register("hash")
 class HashDispatch(DispatchPolicy):
     """Power-of-two-choices over consistent hashing (legacy Router)."""
@@ -279,6 +324,17 @@ class HashDispatch(DispatchPolicy):
             return a if out[a] <= out[b] else b
         return a if (self.views[a].outstanding()
                      <= self.views[b].outstanding()) else b
+
+    def route_many(self, rids: Sequence[int]) -> np.ndarray:
+        """``route`` for a batch against one snapshot of the bound
+        columns (unmasked): the frontend's hash semantics, every
+        arrival of a tick routed before any is delivered."""
+        n = len(self.views)
+        a = _hash_many(rids, 1) % n
+        b = _hash_many(rids, 2) % n
+        b = np.where(b == a, (a + 1) % n, b)
+        out = self.columns.refresh().outstanding
+        return np.where(out[a] <= out[b], a, b)
 
 
 @DISPATCH_REGISTRY.register("least-outstanding")
@@ -363,17 +419,20 @@ class SFSAwareDispatch(DispatchPolicy):
         self._keys = None          # cached packed argmin keys
         self._pack_ok = True
 
-    def _observe(self, t: float):
-        if self._last_arrival is not None:
-            self._iats.append(t - self._last_arrival)
+    def _observe(self, t: float, m: int = 1) -> list:
+        """Observe ``m`` arrivals at ``t``; returns ``[(k, S), ...]``,
+        the slice from arrival ``k`` of them on, one per update."""
+        first = (None if self._last_arrival is None
+                 else t - self._last_arrival)
         self._last_arrival = t
-        self._since_update += 1
-        if (self._since_update >= self.window
-                and len(self._iats) == self.window):
-            mean_iat = sum(self._iats) / len(self._iats)
-            self.S = max(mean_iat * self.total_lanes, 1e-9)
-            self._since_update = 0
+        self._since_update, fires = observe_instant(
+            self._iats, self.window, self._since_update, first, m)
+        out = []
+        for k, total in fires:
+            self.S = max(total / self.window * self.total_lanes, 1e-9)
             self.slice_timeline.append((t, self.S))
+            out.append((k, self.S))
+        return out
 
     def _refresh_keys(self, c):
         """Packed int64 routing keys over freshly-refreshed columns.
@@ -481,6 +540,55 @@ class SFSAwareDispatch(DispatchPolicy):
                    key=lambda i: (self.views[i].outstanding()
                                   - self.views[i].fair_load(),
                                   self.views[i].outstanding(), i))
+
+    def route_many(self, etas: Sequence, t: float,
+                   deliver) -> Optional[list]:
+        """``route`` for one instant's arrivals in order, over the bound
+        columns (unmasked), with each pick delivered before the next:
+        the same picks, slice updates and bypasses as successive
+        ``route`` calls each followed by its delivery.
+
+        ``deliver(i, cols)`` is the owner's model of one delivery: it
+        applies to server ``i`` of ``cols`` = ``[outstanding,
+        filter_free, queue_len, fair_load]`` (lists) what the delivery
+        does to those columns.  Only the chosen server's packed keys
+        are rewritten between picks.  Returns None before any routing
+        state moves when the batch could outgrow the packed key fields
+        (callers then route one by one, over ``np.lexsort``)."""
+        c = self.columns.refresh()
+        # keys first: the refresh's changed rows are only reported once
+        keys = self._refresh_keys(c)
+        n = len(etas)
+        if (keys is None or c.queue_len.max(initial=0) + n >= _PACK
+                or c.outstanding.max(initial=0) + n >= _PACK):
+            return None
+        ks, kl = keys
+        outa = c.outstanding.copy()
+        cols = [c.outstanding.tolist(), c.filter_free.tolist(),
+                c.queue_len.tolist(), c.fair_load.tolist()]
+        out, ff, ql, fair = cols
+        lanes = c.lanes.tolist()
+        # the slice from each arrival on: (first index, S) per update
+        segs = [(0, self.S)] + self._observe(t, n) + [(n, None)]
+        picks = []
+        for (k0, S), (k1, _) in zip(segs, segs[1:]):
+            thr = self.overload_factor * S
+            for eta in etas[k0:k1]:
+                if eta is None or eta <= S:
+                    best = int(ks.argmin())
+                    if (ff[best] == 0
+                            and ql[best] * S / max(lanes[best], 1) >= thr):
+                        self.overload_bypasses += 1
+                        best = int(outa.argmin())
+                else:
+                    best = int(kl.argmin())
+                deliver(best, cols)
+                o = out[best]
+                ks[best] = (-ff[best] << 42) + (ql[best] << 21) + o
+                kl[best] = (o - fair[best]) * (1 << 21) + o
+                outa[best] = o
+                picks.append(best)
+        return picks
 
 
 POLICIES = tuple(DISPATCH_REGISTRY)

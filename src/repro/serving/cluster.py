@@ -121,7 +121,8 @@ class ClusterFrontend:
     hooks: ``_submit`` (deliver a routed request to server ``idx``),
     ``_step`` (advance every server one tick), ``_active_counts``
     (per-server running-request counts for the tick log),
-    ``_finished_count`` and ``_collect`` (result extraction).
+    ``_finished_count`` and ``_collect`` (result extraction); and may
+    override ``_route_tick`` with a batched intake of equal results.
     """
 
     def __init__(self, views: Sequence[ServerView],
@@ -482,22 +483,10 @@ class ClusterFrontend:
                 load += 1
         return kept
 
-    def tick(self, arrivals: Sequence[Request] = ()):
-        """Dispatch this tick's arrivals, drain pulls, tick every engine."""
-        if (self._fail_at is not None or self._scaler is not None
-                or self._timeline is not None
-                or self._watchdog is not None):
-            self._lifecycle_tick()
-        tr, prof = self._trace, self._prof
-        if tr is not None and arrivals:
-            t = self.t
-            for r in arrivals:
-                tr.emit(t, "arrival", r.rid)
-        if (arrivals and self._watchdog is not None
-                and self._watchdog.shed is not None):
-            arrivals = self._shed_filter(arrivals)
-        if prof is not None:
-            prof.begin("route")
+    def _route_tick(self, arrivals: Sequence[Request]):
+        """Route and deliver one tick's arrivals, one at a time (the
+        specification; a backend may override it with a batched path
+        that gives the same results)."""
         if isinstance(self.policy, HashDispatch):
             # legacy Router semantics: route the whole tick's batch
             # against pre-delivery state (p2c comparisons unaffected by
@@ -514,6 +503,24 @@ class ClusterFrontend:
                     self.central_queue.append(req)
                 else:
                     self._deliver(idx, req)
+
+    def tick(self, arrivals: Sequence[Request] = ()):
+        """Dispatch this tick's arrivals, drain pulls, tick every engine."""
+        if (self._fail_at is not None or self._scaler is not None
+                or self._timeline is not None
+                or self._watchdog is not None):
+            self._lifecycle_tick()
+        tr, prof = self._trace, self._prof
+        if tr is not None and arrivals:
+            t = self.t
+            for r in arrivals:
+                tr.emit(t, "arrival", r.rid)
+        if (arrivals and self._watchdog is not None
+                and self._watchdog.shed is not None):
+            arrivals = self._shed_filter(arrivals)
+        if prof is not None:
+            prof.begin("route")
+        self._route_tick(arrivals)
         # pull drain: submit() updates engine capacity immediately, so the
         # loop terminates once every engine is lane- or slot-saturated.
         if self.central_queue and isinstance(self.policy, PullDispatch):

@@ -50,12 +50,14 @@ from __future__ import annotations
 
 from collections import deque
 from functools import lru_cache, partial
+from itertools import repeat
 from typing import Optional, Sequence
 
 import numpy as np
 
-from repro.core.dispatch import (BoundedTimeline, ServerStateColumns,
-                                 ServerView)
+from repro.core.dispatch import (BoundedTimeline, HashDispatch,
+                                 ServerStateColumns, ServerView,
+                                 SFSAwareDispatch, observe_instant)
 from repro.core.spec import ServerSpec
 from repro.serving.cluster import ClusterConfig, ClusterFrontend
 from repro.serving.request import Request
@@ -421,6 +423,18 @@ def _build_fns(G, L, QCAP, CAP, sfs, trace=False):
     return step, jax.jit(scan_fn), adv
 
 
+def _group_ranks(keys: np.ndarray) -> np.ndarray:
+    """Each entry's rank among the entries with its key, in order: the
+    grouped cumulative count, via one stable argsort."""
+    o = np.argsort(keys, kind="stable")
+    sk = keys[o]
+    ar = np.arange(len(keys))
+    first = np.r_[True, sk[1:] != sk[:-1]]
+    rank = np.empty(len(keys), np.int64)
+    rank[o] = ar - np.maximum.accumulate(np.where(first, ar, 0))
+    return rank
+
+
 def _grow_np(a: np.ndarray, axis: int, size: int, fill=0) -> np.ndarray:
     shape = list(a.shape)
     shape[axis] = size - a.shape[axis]
@@ -533,19 +547,41 @@ class _JaxGroup:
             prof.end("jax_grow")
 
     # -- arrivals (host-classified, device-scattered) ------------------
-    def _observe_iat(self, j: int, t: int):
+    def _observe_iat(self, j: int, t: int, m: int = 1):
+        """``m`` slot-taking arrivals at engine ``j``, all at ``t``,
+        through its adaptive-slice window."""
         if self.fixed_slice is not None:
             return
-        if self._last_arrival[j] >= 0:
-            self._iats[j].append(t - int(self._last_arrival[j]))
-        self._last_arrival[j] = t
-        self._since_update[j] += 1
-        if (self._since_update[j] >= self.window
-                and len(self._iats[j]) == self.window):
-            mean_iat = sum(self._iats[j]) / len(self._iats[j])
-            self.S[j] = max(1, int(round(mean_iat * self.lanes)))
-            self._since_update[j] = 0
+        lj = int(self._last_arrival[j])
+        self._since_update[j], fires = observe_instant(
+            self._iats[j], self.window, int(self._since_update[j]),
+            None if lj < 0 else t - lj, m)
+        for _k, total in fires:
+            self.S[j] = max(1, int(round(total / self.window * self.lanes)))
             self.slice_timeline[j].append((t, int(self.S[j])))
+        self._last_arrival[j] = t
+
+    def _observe_iats(self, ms: np.ndarray, t: int):
+        """:meth:`_observe_iat` of ``ms[j]`` arrivals at every engine
+        ``j``.  Engines whose count stays below the window cannot
+        recompute their slice: they only append their IATs."""
+        if self.fixed_slice is not None:
+            return
+        js = np.nonzero(ms)[0]
+        quiet = self._since_update[js] + ms[js] < self.window
+        jq = js[quiet]
+        iats = self._iats
+        for j, lj, m in zip(jq.tolist(), self._last_arrival[jq].tolist(),
+                            ms[jq].tolist()):
+            d = iats[j]
+            if lj >= 0:
+                d.append(t - lj)
+            if m > 1:
+                d.extend(repeat(0, m - 1))
+        self._since_update[jq] += ms[jq]
+        self._last_arrival[jq] = t
+        for j in js[~quiet].tolist():
+            self._observe_iat(j, t, int(ms[j]))
 
     def _classify(self, j: int, row: int, req: Request, t: int):
         """The numpy ``_on_arrival`` split, minus the region write: the
@@ -583,6 +619,39 @@ class _JaxGroup:
         else:
             self.pending[j].append((row, req))
             self.pending_len[j] += 1
+
+    def submit_many(self, js: np.ndarray, rows: np.ndarray,
+                    reqs: Sequence[Request], t: int):
+        """:meth:`submit` of ``reqs[k]`` (already in the store at
+        ``rows[k]``) to engine ``js[k]``, for each ``k`` in order: the
+        same mirrors, pending deques, slice windows and arrival batch.
+        Hinted demotion is not modelled (callers submit one by one)."""
+        cnt = np.bincount(js, minlength=self.G)
+        self.outstanding += cnt
+        # the first free_slots arrivals at an engine take a slot, in
+        # arrival order; the rest wait in its pending deque
+        take = _group_ranks(js) < self.free_slots[js]
+        ntake = np.minimum(cnt, self.free_slots)
+        self.free_slots -= ntake
+        if not take.all():
+            for k in np.nonzero(~take)[0].tolist():
+                self.pending[js[k]].append((int(rows[k]), reqs[k]))
+            self.pending_len += cnt - ntake
+            js, rows = js[take], rows[take]
+        if self.policy == "cfs":
+            kind = 1
+            self.cfs_count += ntake
+        else:
+            kind = 0
+            self._observe_iats(ntake, t)
+            self.qlen += ntake
+        b = np.empty((len(js), 5), np.int64)
+        b[:, 0] = js
+        b[:, 1] = kind
+        b[:, 2] = rows
+        b[:, 3] = self.store.rid[rows]
+        b[:, 4] = self.store.n_tokens[rows]
+        self._batch.extend(b.ravel().tolist())
 
     def _admit_pending(self, t: int):
         for j in np.nonzero((self.pending_len > 0)
@@ -631,15 +700,8 @@ class _JaxGroup:
             self.ACAP *= 2
         arr = np.full((self.ACAP, _NA), -1, np.int32)
         if batch:
-            # per-(engine, region) arrival ranks in batch order — the
-            # grouped cumulative count, via one stable argsort
-            gid = bj * 2 + kc
-            o = np.argsort(gid, kind="stable")
-            sg = gid[o]
-            ar = np.arange(len(b))
-            first = np.r_[True, sg[1:] != sg[:-1]]
-            rank = np.empty(len(b), np.int64)
-            rank[o] = ar - np.maximum.accumulate(np.where(first, ar, 0))
+            # per-(engine, region) arrival ranks in batch order
+            rank = _group_ranks(bj * 2 + kc)
             qbase = self.qlen - nq            # depth before this batch
             pbase = self.cfs_count - npl
             pos = np.where(kc, pbase[bj] + rank,
@@ -976,6 +1038,33 @@ class _JaxColumns(ServerStateColumns):
         super().__init__(views)
         self._groups = [(g, np.asarray(g.members, np.int64))
                         for g in groups]
+        self._sfs = [v.group.policy == "sfs" for v in self.views]
+        self._slots: list = []
+
+    def begin_intake(self):
+        """Snapshot every server's free slots for :meth:`deliver`."""
+        slots = np.empty(len(self.views), np.int64)
+        for g, m in self._groups:
+            slots[m] = g.free_slots
+        self._slots = slots.tolist()
+
+    def deliver(self, i: int, cols):
+        """The change one ``_JaxGroup.submit`` to server ``i`` makes to
+        its columns ``cols`` = ``[outstanding, filter_free, queue_len,
+        fair_load]``, counting slots from :meth:`begin_intake`: one
+        more outstanding; with a free slot, the request enters the
+        FILTER queue (sfs) or the pool (cfs), so one idle FILTER lane
+        fewer."""
+        out, ff, ql, fair = cols
+        out[i] += 1
+        if self._slots[i] > 0:
+            self._slots[i] -= 1
+            if ff[i] > 0:
+                ff[i] -= 1
+            if self._sfs[i]:
+                ql[i] += 1
+            else:
+                fair[i] += 1
 
     def _pull(self, i: int):
         # one delivery dirties one server between consecutive arrivals —
@@ -1052,6 +1141,10 @@ class JaxCluster(ClusterFrontend):
         super().__init__(views, cfg)
         self._cols = _JaxColumns(views, self.groups)
         self.policy.columns = self._cols
+        self._group_of = np.array([self.groups.index(g)
+                                   for g, _ in self._backend], np.int64)
+        self._member_of = np.array([j for _, j in self._backend], np.int64)
+        self._hinted = any(g.hinted_demotion for g in self.groups)
         self._done_rows: list[int] = []
         self._scan_cooldown = 0
 
@@ -1065,6 +1158,62 @@ class JaxCluster(ClusterFrontend):
         group, j = self._backend[idx]
         group.submit(j, req, self.t)
         self._cols.mark(idx)
+
+    def _route_tick(self, arrivals: Sequence[Request]):
+        """Batched intake: estimate, route and deliver the whole tick
+        in a few calls, with the per-arrival path's results.  Taken for
+        hash and sfs-aware dispatch over every server, with no warm
+        set, trace, watchdog or hinted demotion; else one by one."""
+        pol = self.policy
+        if (not arrivals or pol.active is not None
+                or not isinstance(pol, (HashDispatch, SFSAwareDispatch))
+                or self._warm is not None or self._trace is not None
+                or self._watchdog is not None or self._hinted
+                or any(r.stall_events for r in arrivals)):
+            return super()._route_tick(arrivals)
+        prof = self._prof
+        if prof is not None:
+            prof.begin("route_batch")
+        est = self.predictor.estimate
+        etas = [est(r.func_id, r.eta_hint) for r in arrivals]
+        rids = [r.rid for r in arrivals]
+        if isinstance(pol, HashDispatch):
+            idxs = pol.route_many(rids)
+        else:
+            self._cols.begin_intake()
+            picks = pol.route_many(etas, self.t, self._cols.deliver)
+            if picks is None:
+                if prof is not None:
+                    prof.end("route_batch")
+                return super()._route_tick(arrivals)
+            idxs = np.array(picks, np.int64)
+        self.eta_log.update(zip(rids, etas))
+        ser = self._series
+        if ser is not None:
+            hits = len(etas) - etas.count(None)
+            ser.counters["predictor_hits"] += hits
+            ser.counters["predictor_misses"] += len(etas) - hits
+        for r, eta in zip(arrivals, etas):
+            if r.eta_hint is None and eta is not None:
+                r.eta_hint = eta
+        pol.dispatch_counts = (np.asarray(pol.dispatch_counts)
+                               + np.bincount(idxs, minlength=self.n_servers)
+                               ).tolist()
+        rows = self.store.add_many(arrivals)
+        js = self._member_of[idxs]
+        if len(self.groups) == 1:
+            self.groups[0].submit_many(js, rows, arrivals, self.t)
+        else:
+            gof = self._group_of[idxs]
+            for gi, group in enumerate(self.groups):
+                sel = np.nonzero(gof == gi)[0]
+                if sel.size:
+                    group.submit_many(js[sel], rows[sel],
+                                      [arrivals[k] for k in sel.tolist()],
+                                      self.t)
+        self._cols.mark_all()
+        if prof is not None:
+            prof.end("route_batch")
 
     def _evict_server(self, idx: int) -> list:
         group, j = self._backend[idx]

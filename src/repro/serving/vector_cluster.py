@@ -95,19 +95,36 @@ class _RequestStore:
                "first_start", "queue_enter", "queue_delay", "finish",
                "in_filter", "in_cfs", "pool_pos", "mark")
 
+    def _reserve(self, n: int):
+        """Grow every column (doubling) to hold ``n`` rows."""
+        size = self.rid.size
+        while size < n:
+            size *= 2
+        for name in self._ARRAYS:
+            fill = (-1 if name in ("first_start", "finish", "pool_pos")
+                    else 0)
+            setattr(self, name, _grow(getattr(self, name), size, fill))
+
     def add(self, req: Request) -> int:
         if self.n == self.rid.size:
-            for name in self._ARRAYS:
-                a = getattr(self, name)
-                fill = (-1 if name in ("first_start", "finish", "pool_pos")
-                        else 0)
-                setattr(self, name, _grow(a, 2 * a.size, fill))
+            self._reserve(self.n + 1)
         row = self.n
         self.n += 1
         self.reqs.append(req)
         self.rid[row] = req.rid
         self.n_tokens[row] = req.n_tokens
         return row
+
+    def add_many(self, reqs: Sequence[Request]) -> np.ndarray:
+        """:meth:`add` for each request in order; returns their rows."""
+        r0, r1 = self.n, self.n + len(reqs)
+        if r1 > self.rid.size:
+            self._reserve(r1)
+        self.n = r1
+        self.reqs.extend(reqs)
+        self.rid[r0:r1] = [r.rid for r in reqs]
+        self.n_tokens[r0:r1] = [r.n_tokens for r in reqs]
+        return np.arange(r0, r1)
 
     def write_back(self, row: int):
         """Materialize a finished row into its Request, matching every

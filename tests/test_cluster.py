@@ -295,6 +295,37 @@ def test_slice_timeline_bounded_on_long_runs():
     assert ts == sorted(ts)
 
 
+@pytest.mark.parametrize("window,since,filled,first,m", [
+    (4, 0, 0, None, 1), (4, 3, 4, 5, 1), (4, 0, 2, 7, 3),
+    (4, 2, 4, 1, 11), (3, 2, 1, None, 9), (100, 99, 100, 2, 250),
+    (1, 0, 1, 3, 5)])
+def test_observe_instant_equals_one_arrival_at_a_time(window, since, filled,
+                                                      first, m):
+    """m arrivals at one instant through an IAT window: the same window,
+    count and slice updates (with their arrival index and window sum) as
+    m single observations, the first with its IAT and the rest with 0."""
+    from collections import deque
+
+    from repro.core.dispatch import observe_instant
+    seed = list(range(1, filled + 1))
+    d_many = deque(seed, maxlen=window)
+    since_many, fires = observe_instant(d_many, window, since, first, m)
+    d_one, s_one, want = deque(seed, maxlen=window), since, []
+    for k in range(m):
+        s_one, f = observe_instant(d_one, window, s_one,
+                                   first if k == 0 else 0, 1)
+        want.extend((k, total) for _, total in f)
+    assert (list(d_many), since_many, fires) == (list(d_one), s_one, want)
+
+
+def test_hash_many_equals_hash():
+    from repro.core.dispatch import _hash, _hash_many
+    rids = [0, 1, 7, 99, 12345, 2**31 + 5, -3]
+    for salt in (1, 2):
+        assert _hash_many(rids, salt).tolist() == [_hash(r, salt)
+                                                   for r in rids]
+
+
 def test_bounded_timeline_decimation_semantics():
     from repro.core.dispatch import BoundedTimeline
     tl = BoundedTimeline(cap=8)

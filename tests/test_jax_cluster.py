@@ -11,7 +11,9 @@ import pytest
 
 import repro.serving.jax_cluster as jc_mod
 from repro.core.spec import ServerSpec
+from repro.core.telemetry import Telemetry
 from repro.serving import ClusterConfig, Request, VectorCluster
+from repro.serving.cluster import ClusterFrontend
 from repro.serving.jax_cluster import _SCAN_CHUNK, JaxCluster
 
 
@@ -264,6 +266,188 @@ def test_region_growth_under_single_tick_flood():
     vec = VectorCluster(specs, ClusterConfig(policy="hash"))
     want = vec.run(_flood_workload(), max_ticks=200_000)
     assert fingerprint(got) == fingerprint(want)
+
+
+# ---------------------------------------------------------------------------
+# Batched intake: one routing call and one submit per group a tick
+# ---------------------------------------------------------------------------
+
+
+class PerArrival(JaxCluster):
+    """The jax backend with the frontend's one-by-one intake: the
+    reference the batched intake must match."""
+    _route_tick = ClusterFrontend._route_tick
+
+
+class Witness(JaxCluster):
+    """The batched intake, recording the most arrivals one server took
+    in a tick and the deepest pending deque after an intake."""
+
+    def __init__(self, *args):
+        super().__init__(*args)
+        self.seen = {"burst": 0, "pending": 0}
+
+    def _route_tick(self, arrivals):
+        before = np.array(self.policy.dispatch_counts)
+        super()._route_tick(arrivals)
+        seen = self.seen
+        seen["burst"] = max(seen["burst"], int(
+            (np.array(self.policy.dispatch_counts) - before).max()))
+        seen["pending"] = max(seen["pending"], max(
+            int(g.pending_len.max()) for g in self.groups))
+
+
+def _intake_workload(n, per_tick, seed, every=1, funcs=6):
+    """``per_tick`` arrivals every ``every`` ticks; each function has
+    its own duration, so the history predictor learns distinct ETAs."""
+    rng = np.random.default_rng(seed)
+    dur = rng.integers(2, 60, funcs)
+    out = []
+    for i in range(n):
+        f = int(rng.integers(funcs))
+        ntok = max(1, int(dur[f] + rng.integers(-1, 2)))
+        out.append(Request(rid=i, arrival=(i // per_tick) * every,
+                           prompt_len=4, n_tokens=ntok, eta_hint=ntok + 1,
+                           func_id=f))
+    return out
+
+
+def _intake_state(c):
+    """Every result and piece of routing state the intake writes."""
+    p = c.policy
+    return dict(
+        dispatch_counts=c.dispatch_counts, eta_log=c.eta_log,
+        summary=c.summary(), S=getattr(p, "S", None),
+        slice_timeline=list(getattr(p, "slice_timeline", ())),
+        iats=list(getattr(p, "_iats", ())),
+        groups=[(g.S.tolist(), [list(x) for x in g.slice_timeline],
+                 [list(d) for d in g._iats], g._since_update.tolist(),
+                 g._last_arrival.tolist()) for g in c.groups])
+
+
+_SFS4 = (ServerSpec(cores=2, slots=4),) * 4
+_MIXED = ((ServerSpec(cores=4),) * 3
+          + (ServerSpec(cores=2, scheduler="cfs"),) * 2)
+_WIDE = (ServerSpec(cores=4, slots=512),) * 2
+
+INTAKE_CASES = {
+    # 20 arrivals a tick on 4 x 4 slots: most wait in pending deques
+    "sfs-aware-slots-exhausted": (_SFS4, "sfs-aware", "history",
+                                  (600, 20, 1),
+                                  lambda c, s: s["pending"] > 0),
+    "hash-slots-exhausted": (_SFS4, "hash", "history", (600, 20, 1),
+                             lambda c, s: s["pending"] > 0),
+    # 400 arrivals a tick on two servers: the dispatcher's bypass fires
+    # and each server takes more than adaptive_window (100) in a tick
+    "sfs-aware-bypass-wide-burst": (
+        _WIDE, "sfs-aware", "oracle", (1200, 400, 3, 300),
+        lambda c, s: c.policy.overload_bypasses > 0 and s["burst"] > 100),
+    "hash-wide-burst": (_WIDE, "hash", "oracle", (1200, 400, 3, 300),
+                        lambda c, s: s["burst"] > 100),
+    # an sfs group and a cfs group behind one dispatcher
+    "sfs-aware-mixed-groups": (_MIXED, "sfs-aware", "history",
+                               (800, 12, 2),
+                               lambda c, s: len(c.groups) == 2),
+    "hash-mixed-groups": (_MIXED, "hash", "oracle", (800, 12, 2),
+                          lambda c, s: len(c.groups) == 2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(INTAKE_CASES))
+def test_batched_intake_matches_per_arrival(case):
+    """The batched intake (route_batch engaged on every tick with
+    arrivals) == the one-by-one intake, field for field, with the same
+    routing state: dispatch counts, ETA log, bypasses, both levels of
+    adaptive slice."""
+    specs, policy, predictor, wl, witness = INTAKE_CASES[case]
+    cfg = ClusterConfig(policy=policy, predictor=predictor)
+    runs = []
+    for cls in (Witness, PerArrival):
+        tel = Telemetry(profile=True)
+        c = cls(specs, cfg)
+        c.attach_telemetry(tel)
+        got = c.run(_intake_workload(*wl), max_ticks=200_000)
+        runs.append((c, tel.profile.phases, got))
+    (bc, bph, bgot), (rc, rph, rgot) = runs
+    assert witness(bc, bc.seen), case
+    arrival_ticks = len({r.arrival for r in _intake_workload(*wl)})
+    assert bph["route_batch"][1] == arrival_ticks
+    assert "route_batch" not in rph
+    assert fingerprint(bgot) == fingerprint(rgot)
+    assert _intake_state(bc) == _intake_state(rc)
+
+
+def test_batched_intake_falls_back_when_keys_cannot_pack(monkeypatch):
+    """A tick whose counters could outgrow the packed key fields routes
+    one by one over np.lexsort, with the same results."""
+    import repro.core.dispatch as dispatch_mod
+    monkeypatch.setattr(dispatch_mod, "_PACK", 24)
+    batched = []
+    submit_many = jc_mod._JaxGroup.submit_many
+    monkeypatch.setattr(jc_mod._JaxGroup, "submit_many",
+                        lambda g, *a: batched.append(1) or submit_many(g, *a))
+    specs, wl = (ServerSpec(cores=2, slots=64),) * 3, (400, 8, 4)
+    cfg = ClusterConfig(policy="sfs-aware")
+    runs = []
+    for cls in (Witness, PerArrival):
+        c = cls(specs, cfg)
+        runs.append((c, c.run(_intake_workload(*wl), max_ticks=200_000)))
+    (bc, bgot), (rc, rgot) = runs
+    # some ticks were batched, the rest routed over np.lexsort
+    assert 0 < len(batched) < len({r.arrival for r in _intake_workload(*wl)})
+    assert bc.policy._keys is None
+    assert fingerprint(bgot) == fingerprint(rgot)
+    assert _intake_state(bc) == _intake_state(rc)
+
+
+@pytest.mark.parametrize("fallback", [None, "masked", "warm_set", "trace"])
+def test_route_batch_span_counts_ticks_with_arrivals(fallback):
+    """One route_batch call per tick that had arrivals, nested in
+    route; masked routing, a warm set and a trace collector take the
+    one-by-one intake, so the span is absent there."""
+    wl = _intake_workload(300, 10, 5, every=3)
+    cfg = ClusterConfig(
+        policy="sfs-aware",
+        scaling="scale:min=2,T=50" if fallback == "masked" else None,
+        lifecycle="lifecycle:cold=3" if fallback == "warm_set" else None)
+    tel = Telemetry(profile=True, trace=fallback == "trace")
+    c = JaxCluster([ServerSpec(cores=2)] * 4, cfg)
+    c.attach_telemetry(tel)
+    c.run(wl, max_ticks=200_000)
+    ph = tel.profile.phases
+    if fallback is None:
+        assert ph["route_batch"][1] == len({r.arrival for r in wl})
+        assert ph["route_batch"][0] <= ph["route"][0]
+    else:
+        if fallback == "masked":
+            assert c.policy.active is not None
+        assert "route_batch" not in ph
+
+
+@pytest.mark.parametrize("scheduler", ["sfs", "cfs"])
+def test_columns_delivery_model_matches_pull(scheduler):
+    """_JaxColumns.deliver, the backend's model of one delivery, equals
+    the columns pulled after a real submit: with free slots and after
+    they run out, with idle FILTER lanes and after they are gone."""
+    specs = [ServerSpec(cores=2, slots=3, scheduler=scheduler),
+             ServerSpec(cores=2, slots=3)]
+    c = JaxCluster(specs, ClusterConfig(policy="sfs-aware"))
+    cols = c._cols
+    for k in range(6):
+        for i in (0, 1):
+            cols.refresh()
+            model = [a.tolist() for a in (cols.outstanding, cols.filter_free,
+                                          cols.queue_len, cols.fair_load)]
+            cols.begin_intake()
+            cols.deliver(i, model)
+            c._submit(i, Request(rid=2 * k + i, arrival=0, prompt_len=4,
+                                 n_tokens=5))
+            cols.refresh()
+            assert model == [a.tolist() for a in (
+                cols.outstanding, cols.filter_free, cols.queue_len,
+                cols.fair_load)], (scheduler, k, i)
+    g = c.groups[0]
+    assert g.pending_len.max() > 0 and (g.free_slots == 0).all()
 
 
 # ---------------------------------------------------------------------------
