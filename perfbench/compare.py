@@ -1,6 +1,6 @@
 """The comparison that decides ``correct``: one experiment of the window,
-drawn from the seed, against the plain reference (``reference.py``) on
-the same requests.
+drawn from the seed, against the configuration's plain reference
+(``references/<reference>.py``) on the same requests.
 
 Every number is a count of differences and the semantics are exact, so
 every limit is 0 (the readings each was set from are in PERF.md):

@@ -1,8 +1,9 @@
 #!/usr/bin/env python3
-"""Readings of the control: the plain reference with one guarantee of the
-configuration broken (its ``control`` key, see ``reference.py``), put in
-the program's place and compared with the clean reference exactly as a
-run compares the program.  The control has to come out not correct.
+"""Readings of the control: the configuration's plain reference
+(``references/<reference>.py``) with one guarantee of the configuration
+broken (its ``control`` key), put in the program's place and compared
+with the clean reference exactly as a run compares the program.  The
+control has to come out not correct.
 
     python3 perfbench/control.py --workload <cell> --seed <n> [--seed ...]
 
@@ -17,9 +18,9 @@ import sys
 import numpy as np
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
+sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]   # a stage's Request
 
-from perfbench import compare, harness, reference, traffic  # noqa: E402
+from perfbench import compare, harness, traffic  # noqa: E402
 
 
 def as_answers(sim: dict) -> dict:
@@ -32,6 +33,7 @@ def as_answers(sim: dict) -> dict:
 def readings(cfg: dict, mix: dict, seed: int, n: int = None) -> dict:
     lanes = int(cfg["servers"]) * int(cfg["cores"])
     d = traffic.draw(mix, harness.experiment_seed(seed, 0), lanes, n=n)
+    reference = harness.reference(cfg)
     ref = reference.simulate(d, cfg)
     ctl = reference.simulate(d, cfg, control=cfg["control"])
     return compare.compare(as_answers(ctl), ref)
@@ -46,6 +48,8 @@ def main(argv=None) -> int:
     cell = harness.entry(bench["workloads"], a.workload, "workload")
     cfg = harness.config(cell["config"])
     mix = traffic.load(cell["traffic"])
+    harness.check_config(cfg)
+    traffic.check(mix)
     for s in a.seed:
         checks = readings(cfg, mix, s)
         print(json.dumps({"workload": a.workload, "seed": s,

@@ -3,10 +3,19 @@
 A cell names a configuration (``configs/<name>.json``) and a traffic mix
 (``traffic/<name>.json``); ``BENCHMARK.json`` lists the cells and the
 metrics, and each per-layer metric is read by ``metrics/<name>.py``.
-Nothing here knows a cell, a mix or a metric by name.
+A configuration names its plain reference (``references/<name>.py``)
+and may carry ``fleet`` knobs, which the program's spec grammar parses
+and the reference has to model; a mix may list traffic stages
+(``traffic.py``).  Nothing here knows a cell, a mix, a reference or a
+metric by name.
 
 A run:
 
+0. refusal — a configuration or mix with a key that no code reads, a
+   reference or stage that does not exist, a scheduler, dispatch or
+   predictor that the reference does not simulate, a ``fleet`` knob
+   that the reference does not model or that the program's grammar
+   rejects: exit 2 with no result, before anything is run;
 1. set-up — finds the chips (none: exit 2, no result), builds the spec,
    and runs warm-up experiments of the cell's own configuration and
    traffic (the mix's ``warmup`` gives their ``n``; their seeds lie
@@ -21,8 +30,8 @@ A run:
    ``--trace 1`` attaches a profile-only telemetry session to every
    experiment and takes a device profile of the first;
 3. check — once the window has closed and peak memory is read, one
-   experiment drawn from the seed is recomputed by the plain reference
-   and compared (``compare.py``).
+   experiment drawn from the seed is recomputed by the configuration's
+   plain reference and compared (``compare.py``).
 """
 from __future__ import annotations
 
@@ -38,7 +47,7 @@ import traceback
 
 import numpy as np
 
-from perfbench import compare, reference, system, trace_reduce, traffic
+from perfbench import compare, lookup, system, trace_reduce, traffic
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -69,10 +78,47 @@ def entry(entries: list, name: str, what: str) -> dict:
     raise KeyError(f"no {what} named {name!r} in BENCHMARK.json")
 
 
+# configuration keys that code reads (``control``: ``control.py``)
+CONFIG_KEYS = {"servers", "cores", "slots", "scheduler", "dispatch",
+               "predictor", "reference"}
+CONFIG_OPTIONAL = {"fleet", "name"}
+
+
 def config(name: str) -> dict:
+    """The configuration file ``configs/<name>.json``, as written
+    (:func:`check_config` refuses what nothing reads)."""
     cfg = load_json(os.path.join(HERE, "configs", name + ".json"))
     cfg["name"] = name
     return cfg
+
+
+def reference(cfg: dict):
+    """The configuration's plain reference module."""
+    return lookup.module("references", cfg["reference"])
+
+
+def check_config(cfg: dict):
+    """Refuses (``ValueError``) a configuration with a key that no code
+    reads, a scheduler, dispatch or predictor that its reference does
+    not simulate, a control its reference does not have, or a ``fleet``
+    knob that its reference does not model; returns the reference
+    module."""
+    lookup.check_keys("configuration", cfg, CONFIG_KEYS, CONFIG_OPTIONAL)
+    system.check_fleet(cfg.get("fleet", {}))
+    ref = reference(cfg)
+    for key, values in ref.MODELS.items():
+        if cfg[key] not in values:
+            raise ValueError(f"reference {cfg['reference']!r} simulates "
+                             f"{key} {values}, not {cfg[key]!r}")
+    if "control" in cfg and cfg["control"] not in ref.CONTROLS:
+        raise ValueError(f"reference {cfg['reference']!r} has no control "
+                         f"{cfg['control']!r}; it has {ref.CONTROLS}")
+    blind = sorted(set(cfg.get("fleet", {})) - set(ref.KNOBS))
+    if blind:
+        raise ValueError(f"reference {cfg['reference']!r} does not model "
+                         f"the fleet knob(s) {blind}; it models "
+                         f"{ref.KNOBS or 'none'}")
+    return ref
 
 
 def experiment_seed(seed: int, i: int) -> int:
@@ -156,8 +202,15 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     err = err or sys.stderr
     bench = load_json(os.path.join(ROOT, "BENCHMARK.json"))
     cell = entry(bench["workloads"], workload, "workload")
-    cfg = cfg or config(cell["config"])
-    mix = mix or traffic.load(cell["traffic"])
+    try:
+        cfg = cfg or config(cell["config"])
+        mix = mix or traffic.load(cell["traffic"])
+        ref = check_config(cfg)
+        traffic.check(mix)
+        spec = system.spec(cfg)
+    except (ValueError, TypeError) as e:
+        print(f"no result: refused: {e}", file=err)
+        return 2
     system_run = system_run or system.run
     import jax
     if require_chip:
@@ -178,19 +231,18 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     log = CompileLog()
     jax.monitoring.register_event_duration_secs_listener(log)
     try:
-        return _run(cell, cfg, mix, seed, seconds, trace, t_start, devs,
-                    peaks, bench, log, system_run, out, err)
+        return _run(cell, cfg, mix, spec, ref, seed, seconds, trace,
+                    t_start, devs, peaks, bench, log, system_run, out, err)
     finally:
         jax.monitoring.unregister_event_duration_listener(log)
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           min_s)
 
 
-def _run(cell, cfg, mix, seed, seconds, trace, t_start, devs, peaks,
-         bench, log, system_run, out, err) -> int:
+def _run(cell, cfg, mix, spec, ref_module, seed, seconds, trace, t_start,
+         devs, peaks, bench, log, system_run, out, err) -> int:
     import jax
     lanes = int(cfg["servers"]) * int(cfg["cores"])
-    spec = system.spec(cfg)
 
     # -- set-up: warm-up experiments of this cell's own traffic, until
     # one builds no new program -------------------------------------
@@ -278,7 +330,7 @@ def _run(cell, cfg, mix, seed, seconds, trace, t_start, devs, peaks,
     if failure is None:
         k = int(np.random.default_rng([seed % 2**64, 1]).integers(len(exps)))
         d = traffic.draw(mix, exps[k]["seed"], lanes)
-        ref = reference.simulate(d, cfg)
+        ref = ref_module.simulate(d, cfg)
         checks = compare.compare(exps[k]["answers"], ref)
         correct = compare.passed(checks)
         del d, ref

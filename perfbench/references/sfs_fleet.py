@@ -12,15 +12,22 @@ nothing of the program: the semantics were written down from the paper
 and the repo's tick object engine, and are held here so that no later
 change to the program can move them.
 
+A reference module of the benchmark exposes ``simulate(draws, cfg,
+control=None)``, ``CONTROLS``, ``MODELS`` (the values of a
+configuration's ``scheduler``, ``dispatch`` and ``predictor`` that it
+simulates) and ``KNOBS``, the ``fleet`` knobs of a configuration that it
+models: none here, so the harness refuses a configuration with cold
+starts, scaling, faults or retries against it.
+
 ``control`` breaks one stated guarantee, for the check that the
 comparison catches such a change:
 
 * ``batch_dispatch`` routes a tick's arrivals against the state before
   any of them is delivered (what a one-call-per-tick router on the
   device would do), where sfs-aware promises sequential dispatch;
-* ``pool_ties_by_position`` breaks vruntime ties in the pool by the
-  order requests joined it instead of by rid (what a top-k over pool
-  positions would do).
+* ``sequential_dispatch`` routes each arrival against the state that
+  holds every earlier arrival of its tick, where hash promises routing
+  against the state before the tick's first delivery.
 """
 from __future__ import annotations
 
@@ -29,7 +36,10 @@ from collections import deque
 
 import numpy as np
 
-CONTROLS = ("batch_dispatch", "pool_ties_by_position")
+CONTROLS = ("batch_dispatch", "sequential_dispatch")
+MODELS = {"scheduler": ("sfs",), "dispatch": ("hash", "sfs-aware"),
+          "predictor": ("oracle",)}
+KNOBS = ()
 _B = 1 << 20          # field width of the packed lexicographic keys
 
 
@@ -45,7 +55,7 @@ class _Server:
     def __init__(self, slots: int, slice_init: int, window: int):
         self.queue = deque()        # FILTER's FIFO queue of rids
         self.filt = []              # rids on FILTER lanes, in lane order
-        self.pool = {}              # fair-share pool: rid -> join order
+        self.pool = set()           # fair-share pool
         self.last = []              # rids the pool ran at its last pick
         self.minvr = 0
         self.S = slice_init
@@ -62,13 +72,11 @@ def simulate(draws: dict, cfg: dict, control: str = None) -> dict:
     counts."""
     if control is not None and control not in CONTROLS:
         raise ValueError(f"unknown control {control!r}")
-    if cfg["scheduler"] != "sfs" or cfg["predictor"] != "oracle":
-        raise ValueError("the reference models SFS servers under the "
-                         "oracle ETA hint only")
+    for key, values in MODELS.items():
+        if cfg[key] not in values:
+            raise ValueError(f"the reference models {key} {values}, not "
+                             f"{cfg[key]!r}")
     policy = cfg["dispatch"]
-    if policy not in ("hash", "sfs-aware"):
-        raise ValueError(f"the reference models hash and sfs-aware "
-                         f"dispatch only, not {policy!r}")
     N, L, slots = int(cfg["servers"]), int(cfg["cores"]), int(cfg["slots"])
     window, slice_init, O = 100, 32, 3.0
     arrival = draws["arrival"].tolist()
@@ -85,15 +93,12 @@ def simulate(draws: dict, cfg: dict, control: str = None) -> dict:
     qenter = [0] * n
     srv = [_Server(slots, slice_init, window) for _ in range(N)]
     dispatch = np.zeros(N, np.int64)
-    ties_by_pos = control == "pool_ties_by_position"
-    join = [0]                  # pool join counter (control only)
 
     # dispatch-visible state, one column per quantity
     out = np.zeros(N, np.int64)       # slots held + pending
     ff = np.full(N, L, np.int64)      # idle FILTER lanes net of queue
     ql = np.zeros(N, np.int64)
     fl = np.zeros(N, np.int64)        # pool size
-    idx = np.arange(N, dtype=np.int64)
 
     def refresh(s: int):
         v = srv[s]
@@ -105,8 +110,7 @@ def simulate(draws: dict, cfg: dict, control: str = None) -> dict:
 
     def to_pool(v: _Server, rid: int):
         vr[rid] = v.minvr
-        join[0] += 1
-        v.pool[rid] = join[0]
+        v.pool.add(rid)
 
     def on_arrival(v: _Server, rid: int, t: int):
         if v.last_arr is not None:
@@ -158,12 +162,12 @@ def simulate(draws: dict, cfg: dict, control: str = None) -> dict:
         # long: fewest FILTER-bound requests, then least outstanding
         return int(np.argmin((out - fl) * _B + out))
 
-    def route_hash(rid: int, snapshot) -> int:
+    def route_hash(rid: int) -> int:
         a = _hash(rid, 1) % N
         b = _hash(rid, 2) % N
         if b == a:
             b = (a + 1) % N
-        return a if snapshot[a] <= snapshot[b] else b
+        return a if out[a] <= out[b] else b
 
     def step(s: int, t: int):
         v = srv[s]
@@ -183,11 +187,7 @@ def simulate(draws: dict, cfg: dict, control: str = None) -> dict:
         free = L - len(filt)
         chosen = []
         if free > 0:
-            if ties_by_pos:
-                ranked = sorted(pool, key=lambda r: (vr[r], pool[r]))
-            else:
-                ranked = sorted(pool, key=lambda r: (vr[r], r))
-            chosen = ranked[:free]
+            chosen = sorted(pool, key=lambda r: (vr[r], r))[:free]
             picked = set(chosen)
             for rid in v.last:      # ran at the last pick, passed over now
                 if rid in pool and rid not in picked:
@@ -225,16 +225,24 @@ def simulate(draws: dict, cfg: dict, control: str = None) -> dict:
             tail = chosen[-1]
             for rid in fin:
                 if rid != tail:
-                    del pool[rid]
+                    pool.remove(rid)
             m = min(vr[r] for r in pool)
             if m > v.minvr:
                 v.minvr = m
             if fin and fin[-1] == tail:
-                del pool[tail]
+                pool.remove(tail)
             done_here += len(fin)
         v.free_slots += done_here
         return done_here
 
+    def route(rid: int, t: int) -> int:
+        return (route_hash(rid) if policy == "hash"
+                else route_sfs(rid, t))
+
+    if policy == "hash":
+        sequential = control == "sequential_dispatch"
+    else:
+        sequential = control != "batch_dispatch"
     busy = set()
     done = 0
     i = 0
@@ -247,16 +255,12 @@ def simulate(draws: dict, cfg: dict, control: str = None) -> dict:
             j += 1
         if j > i:
             batch = order[i:j]
-            if policy == "hash":
-                snap = out.copy()
-                targets = [route_hash(rid, snap) for rid in batch]
-            elif control == "batch_dispatch":
-                targets = [route_sfs(rid, t) for rid in batch]
-            else:
-                targets = None
+            # every target before any delivery (batch), or each arrival
+            # against the state that holds the tick's earlier ones
+            targets = (None if sequential
+                       else [route(rid, t) for rid in batch])
             for k, rid in enumerate(batch):
-                s = (targets[k] if targets is not None
-                     else route_sfs(rid, t))
+                s = targets[k] if targets is not None else route(rid, t)
                 deliver(s, rid, t)
                 busy.add(s)
             i = j

@@ -15,7 +15,7 @@ for p in (ROOT, os.path.join(ROOT, "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-from perfbench import compare, control, system  # noqa: E402
+from perfbench import compare, control, harness, system  # noqa: E402
 from perfbench.tests.test_perfbench_harness import (CELLS, run_tiny,  # noqa: E402
                                                     tiny)
 
@@ -67,10 +67,21 @@ def test_broken_timed_path_is_not_correct(fault):
     assert list(res)[-1] == "checks"
 
 
+@pytest.mark.parametrize("fault", [stuck, half_batch, answer_altered,
+                                   departs_from_config],
+                         ids=lambda f: f.__name__)
+@pytest.mark.parametrize("cell_name", CELLS[1:])
+def test_broken_timed_path_is_not_correct_in_other_cells(cell_name, fault):
+    """The same faults in every other cell of ``BENCHMARK.json``."""
+    rc, res, err = run_tiny(cell_name, system_run=fault)
+    assert rc == 0
+    assert res["correct"] is False, err
+
+
 def test_control_in_the_programs_place_is_not_correct():
     """The control put in the program's place, through a whole run."""
-    from perfbench import reference
     _, cfg, mix = tiny(CELLS[0])
+    reference = harness.reference(cfg)
 
     def control_program(spec, reqs, tel=None, max_ticks=None):
         d = {"arrival": np.array([r.arrival for r in reqs]),

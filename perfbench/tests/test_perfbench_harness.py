@@ -83,8 +83,8 @@ def test_benchmark_names_files_of_its_own():
     for c in BENCH["configs"]:
         assert os.path.isfile(os.path.join(ROOT, c["file"]))
     for w in BENCH["workloads"]:
-        harness.config(w["config"])
-        traffic.load(w["traffic"])
+        harness.check_config(harness.config(w["config"]))
+        traffic.check(traffic.load(w["traffic"]))
     for m in BENCH["per_layer"]:
         mod = importlib.import_module(f"perfbench.metrics.{m['name']}")
         assert callable(mod.read)
@@ -116,11 +116,11 @@ def test_warm_up_repeats_until_no_new_program():
 
 
 def test_reference_refuses_an_unknown_dispatch():
-    from perfbench import reference
+    from perfbench.references import sfs_fleet
     _, cfg, mix = tiny(CELLS[0])
     d = traffic.draw(mix, 3, 32, n=100)
     with pytest.raises(ValueError):
-        reference.simulate(d, dict(cfg, dispatch="least-outstanding"))
+        sfs_fleet.simulate(d, dict(cfg, dispatch="least-outstanding"))
 
 
 def test_no_tpu_exits_nonzero_without_result():
