@@ -266,14 +266,18 @@ class HostProfile:
     clock its device events share.  Totals accumulate as
     ``phases[name] = [seconds, calls]``.
 
+    A counter (:meth:`count`) is kept the same way, as calls of no
+    time, and is no annotation; ``counters`` names them.
+
     Phase names are a flat namespace; docs/OBSERVABILITY.md carries the
     glossary (build, route, step, jax_step, jax_sync, jax_scan, ...).
     """
 
-    __slots__ = ("phases", "_open")
+    __slots__ = ("phases", "counters", "_open")
 
     def __init__(self):
         self.phases: dict = {}          # name -> [total_s, count]
+        self.counters: set = set()      # names kept by count()
         self._open: list = []   # (name, annotation, start), innermost last
 
     def add(self, name: str, dt: float):
@@ -283,6 +287,16 @@ class HostProfile:
         else:
             slot[0] += dt
             slot[1] += 1
+
+    def count(self, name: str, n: int):
+        """A counter kept as a span's call count: ``n`` calls of no
+        time (``stall_park``, ``stall_wake``)."""
+        slot = self.phases.get(name)
+        if slot is None:
+            self.phases[name] = [0.0, n]
+            self.counters.add(name)
+        else:
+            slot[1] += n
 
     def begin(self, name: str):
         self._open.append((name, _annotation(name), time.perf_counter()))

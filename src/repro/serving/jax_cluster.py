@@ -42,9 +42,15 @@ asserted across backends in ``tests/test_agreement.py``.  Level 3
 (dispatch, predictors, the central pull queue) is the shared
 :class:`~repro.serving.cluster.ClusterFrontend`, untouched.
 
-Not supported here (submit/build raises): stall events, real-model
-decoding, per-server object-engine pinning — pin those runs to the
-``vector`` or ``tick`` backends instead.
+**Stall events** (§V-D I/O parking) run in the jitted step too, in a
+variant a group compiles only once a request with stall events reaches
+it (:func:`_tick_core`'s ``stl``); a fleet that never stalls lowers the
+same programs as without it.  They are bit-exact with
+``engine="tick"`` (the vector backend refuses them).
+
+Not supported here (build raises): real-model decoding, per-server
+object-engine pinning — pin those runs to the ``vector`` or ``tick``
+backends instead.
 """
 from __future__ import annotations
 
@@ -80,6 +86,23 @@ _NP = 11
 _NE = 10
 _AENG, _AKIND, _AROW, _ARID, _ANTOK, _APOS = range(6)        # arrivals
 _NA = 6
+# stall layout, compiled only for a group that has received a request
+# with stall events: queue rows carry the lane history a woken request
+# brings back, lanes its lane reassignments, and the stalled region
+# holds parked rows in the pool layout plus their wake tick.  Every
+# region row (and arrival row) then ends in the stall tail
+# ``[sti, seq, thr_0, dur_0, ..., thr_{ME-1}, dur_{ME-1}]``: events
+# consumed, slot-acquisition order (wakes re-enter in it), and the
+# request's (tokens_done threshold, stall ticks) pairs, padded with
+# thresholds of _IMAX.
+_QSRV, _QSLC, _QQD, _QFS, _QNCTX, _QFLG = range(4, 10)       # queue
+_NQS = 10
+_LNCTX = 8                                                   # lanes
+_NLS = 9
+_SWAKE = 11                                                  # stalled
+_NSS = 12
+_ESTI = 10                                                   # events
+_NES = 11
 
 _SCAN_CHUNK = 64          # ticks per lax.scan dispatch
 _SCAN_EVCAP_MAX = 4096    # per-tick completion buffer cap inside a chunk
@@ -96,18 +119,31 @@ def _scan_evcap(G: int, L: int, sfs: bool) -> int:
 
 _STATE_KEYS = ("q", "qh", "qn", "lanes", "lc", "pool", "pc", "minvr",
                "last")
+_STALL_KEYS = ("stl", "sc")      # stalled region and its depth
 
 
-def state_shapes(G: int, L: int, QCAP: int, CAP: int) -> dict:
+def _tail(stl) -> int:
+    """Width of the stall tail for the static stall config ``stl`` =
+    ``(SCAP, ME, stall_aware)`` (0 without stalls)."""
+    return 0 if stl is None else 2 + 2 * stl[1]
+
+
+def state_shapes(G: int, L: int, QCAP: int, CAP: int, stl=None) -> dict:
     """Shapes of one group's int32 device state, keyed like
-    ``_STATE_KEYS``."""
-    return dict(q=(G, QCAP, _NQ), qh=(G,), qn=(G,), lanes=(G, L, _NL),
-                lc=(G,), pool=(G, CAP, _NP), pc=(G,), minvr=(G,),
-                last=(G, L))
+    ``_STATE_KEYS`` (and ``_STALL_KEYS`` under a stall config)."""
+    if stl is None:
+        return dict(q=(G, QCAP, _NQ), qh=(G,), qn=(G,), lanes=(G, L, _NL),
+                    lc=(G,), pool=(G, CAP, _NP), pc=(G,), minvr=(G,),
+                    last=(G, L))
+    T = _tail(stl)
+    return dict(q=(G, QCAP, _NQS + T), qh=(G,), qn=(G,),
+                lanes=(G, L, _NLS + T), lc=(G,), pool=(G, CAP, _NP + T),
+                pc=(G,), minvr=(G,), last=(G, L),
+                stl=(G, stl[0], _NSS + T), sc=(G,))
 
 
 def step_arg_specs(G: int, L: int, QCAP: int, CAP: int, ACAP: int,
-                   sharding=None) -> tuple:
+                   sharding=None, stl=None) -> tuple:
     """Shape/dtype structs of ``(state, arr, t, S, thr)``, the
     arguments of the jitted group step, for compiling it without arrays
     (on a described device, or to inspect the compiled program)."""
@@ -117,11 +153,36 @@ def step_arg_specs(G: int, L: int, QCAP: int, CAP: int, ACAP: int,
     def spec(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
 
-    state = {k: spec(*s) for k, s in state_shapes(G, L, QCAP, CAP).items()}
-    return state, spec(ACAP, _NA), spec(), spec(G), spec(G)
+    state = {k: spec(*s)
+             for k, s in state_shapes(G, L, QCAP, CAP, stl).items()}
+    return state, spec(ACAP, _NA + _tail(stl)), spec(), spec(G), spec(G)
 
 
-def _tick_core(G, L, QCAP, CAP, sfs, evcap, trace, state, arr, t, S, thr):
+def _cur_event(tail, ME: int):
+    """The (threshold, ticks) of each row's next stall event, from its
+    stall tail (threshold _IMAX once every event is consumed)."""
+    import jax.numpy as jnp
+    sti = tail[..., 0]
+    thr = jnp.full(sti.shape, _IMAX, jnp.int32)
+    dur = jnp.zeros(sti.shape, jnp.int32)
+    for e in range(ME):
+        hit = sti == e
+        thr = jnp.where(hit, tail[..., 2 + 2 * e], thr)
+        dur = jnp.where(hit, tail[..., 3 + 2 * e], dur)
+    return thr, dur
+
+
+def _park_dist(thr, srv):
+    """Ticks until a row serving every tick reaches its next stall
+    threshold (``tokens_done = srv - 1``), at least 1; _IMAX if none."""
+    import jax.numpy as jnp
+    return jnp.where(thr < _IMAX,
+                     jnp.maximum(jnp.minimum(thr, _IMAX - 1) + 1 - srv, 1),
+                     _IMAX)
+
+
+def _tick_core(G, L, QCAP, CAP, sfs, evcap, trace, state, arr, t, S, thr,
+               stl=None):
     """One tick of a G-engine homogeneous group, pure int32 array ops.
 
     Mirrors ``_VectorGroup.tick`` operation for operation: arrival
@@ -138,6 +199,15 @@ def _tick_core(G, L, QCAP, CAP, sfs, evcap, trace, state, arr, t, S, thr):
     so the host can reconstruct the same lifecycle events the object
     and vector backends emit inline (core/telemetry.py) — the masks are
     captured *before* lane/pool compaction overwrites the rows.
+
+    ``stl`` (static, ``(SCAP, ME, stall_aware)`` or None) compiles the
+    object engine's stall events (§V-D) in: after the arrivals, rows
+    whose wake tick is ``t`` leave the stalled region in slot order, a
+    FILTER row to the queue's tail (``queue_enter = t``, its unused
+    slice kept), a demoted row to the pool at ``max(vr, minvr)``; at
+    tick end a row that ran, did not finish and reached its next stall
+    threshold parks (``n_ctx + 1``, wake at ``t + 1 + ticks``), after
+    its own slice-expiry demotion.
     """
     import jax.numpy as jnp
 
@@ -150,6 +220,12 @@ def _tick_core(G, L, QCAP, CAP, sfs, evcap, trace, state, arr, t, S, thr):
     ic = jnp.arange(CAP, dtype=jnp.int32)
     A = arr.shape[0]
     one32 = jnp.int32(1)
+    stall = stl is not None
+    if stall:
+        SCAP, ME, aware = stl
+        sv, sc = state["stl"], state["sc"]
+        iS = jnp.arange(SCAP, dtype=jnp.int32)
+        atail = arr[:, _NA:]
 
     # ---- arrival scatter (already classified + positioned on host) ----
     kind = arr[:, _AKIND]
@@ -161,6 +237,10 @@ def _tick_core(G, L, QCAP, CAP, sfs, evcap, trace, state, arr, t, S, thr):
         eq = jnp.where(kind == 0, aeng, G)
         qrow = jnp.stack([arr[:, _AROW], arr[:, _ARID], arr[:, _ANTOK],
                           tA], axis=-1)
+        if stall:
+            # fresh: nothing served, no slice, no first start yet
+            qrow = jnp.concatenate([qrow, jnp.stack(
+                [zA, zA, zA, zA - 1, zA, zA], axis=-1), atail], axis=-1)
         q = q.at[eq, apos].set(qrow, mode="drop")
         qn = qn + jnp.zeros(G, jnp.int32).at[eq].add(one32, mode="drop")
     ep = jnp.where(kind >= 1, aeng, G)
@@ -168,8 +248,46 @@ def _tick_core(G, L, QCAP, CAP, sfs, evcap, trace, state, arr, t, S, thr):
     prow = jnp.stack([arr[:, _AROW], arr[:, _ARID], arr[:, _ANTOK],
                       zA, avr, zA, zA, zA - 1, tA,
                       (kind == 2).astype(jnp.int32), zA], axis=-1)
+    if stall:
+        prow = jnp.concatenate([prow, atail], axis=-1)
     pool = pool.at[ep, apos].set(prow, mode="drop")
     pc = pc + jnp.zeros(G, jnp.int32).at[ep].add(one32, mode="drop")
+
+    # ---- wakes: after the arrivals, in slot-acquisition order --------
+    if stall:
+        svalid = iS[None, :] < sc[:, None]
+        wk = svalid & (sv[..., _SWAKE] == t)
+        stail = sv[..., _NSS:]
+        if sfs:
+            wq = wk & ((sv[..., _PFLG] & 1) == 0)      # not demoted
+            wp = wk & ~wq
+            seq = stail[..., 1]
+            rank = jnp.sum(wq[:, None, :] & (seq[:, None, :] < seq[:, :, None]),
+                           axis=-1, dtype=jnp.int32)
+            qpos = jnp.where(wq, (qh[:, None] + qn[:, None] + rank) % QCAP,
+                             QCAP)
+            wrow = jnp.concatenate([jnp.stack(
+                [sv[..., _PROW], sv[..., _PRID], sv[..., _PNTOK],
+                 jnp.zeros((G, SCAP), jnp.int32) + t, sv[..., _PSRV],
+                 sv[..., _PSLC], sv[..., _PQD], sv[..., _PFS],
+                 sv[..., _PNCTX], sv[..., _PFLG]], axis=-1), stail], axis=-1)
+            q = q.at[gi[:, None], qpos].set(wrow, mode="drop")
+            qn = qn + jnp.sum(wq, axis=1, dtype=jnp.int32)
+        else:
+            wp = wk                                      # cfs: all pool
+        pw = jnp.cumsum(wp, axis=1, dtype=jnp.int32) - wp
+        ppos = jnp.where(wp, pc[:, None] + pw, CAP)
+        wprow = jnp.concatenate([
+            sv[..., :_NP].at[..., _PVR].set(
+                jnp.maximum(sv[..., _PVR], minvr[:, None])), stail], axis=-1)
+        pool = pool.at[gi[:, None], ppos].set(wprow, mode="drop")
+        pc = pc + jnp.sum(wp, axis=1, dtype=jnp.int32)
+        skeep = svalid & ~wk
+        sdest = jnp.where(
+            skeep, jnp.cumsum(skeep, axis=1, dtype=jnp.int32) - 1, SCAP)
+        sv = jnp.zeros_like(sv).at[gi[:, None], sdest].set(sv, mode="drop")
+        sc = jnp.sum(skeep, axis=1, dtype=jnp.int32)
+        n_wake = jnp.sum(wk, axis=1, dtype=jnp.int32)
 
     # ---- FILTER fill: the pop loop as a cumulative-sum prefix --------
     n_byp = jnp.zeros(G, jnp.int32)
@@ -194,17 +312,39 @@ def _tick_core(G, L, QCAP, CAP, sfs, evcap, trace, state, arr, t, S, thr):
             tr_byp = jnp.where(bypass, qq[..., _QROW], -1)
         zQ = jnp.zeros((G, QCAP), jnp.int32)
         lane_i = jnp.where(admit, lc[:, None] + adm_before, L)
-        lrow = jnp.stack([qq[..., _QROW], qq[..., _QRID], qq[..., _QNTOK],
-                          zQ, zQ + S[:, None], delay, zQ + t,
-                          qq[..., _QENT]], axis=-1)
+        if stall:
+            # a woken row keeps what it served, its unused slice (a
+            # fresh one if none is left), its first start and its
+            # queue delay so far
+            qfs = jnp.where(qq[..., _QFS] < 0, t, qq[..., _QFS])
+            qqd = qq[..., _QQD] + delay
+            qtail = qq[..., _NQS:]
+            lrow = jnp.concatenate([jnp.stack(
+                [qq[..., _QROW], qq[..., _QRID], qq[..., _QNTOK],
+                 qq[..., _QSRV],
+                 jnp.where(qq[..., _QSLC] > 0, qq[..., _QSLC], S[:, None]),
+                 qqd, qfs, qq[..., _QENT], qq[..., _QNCTX]], axis=-1),
+                qtail], axis=-1)
+        else:
+            lrow = jnp.stack([qq[..., _QROW], qq[..., _QRID],
+                              qq[..., _QNTOK], zQ, zQ + S[:, None], delay,
+                              zQ + t, qq[..., _QENT]], axis=-1)
         lanes = lanes.at[gi[:, None], lane_i].set(lrow, mode="drop")
         n_adm = jnp.sum(admit, axis=1, dtype=jnp.int32)
         lc = lc + n_adm
         bcum = jnp.cumsum(bypass, axis=1, dtype=jnp.int32) - bypass
         bpos = jnp.where(bypass, pc[:, None] + bcum, CAP)
-        brow = jnp.stack([qq[..., _QROW], qq[..., _QRID], qq[..., _QNTOK],
-                          zQ, zQ + minvr[:, None], zQ, delay, zQ + t,
-                          qq[..., _QENT], zQ + 1, zQ], axis=-1)
+        if stall:
+            brow = jnp.concatenate([jnp.stack(
+                [qq[..., _QROW], qq[..., _QRID], qq[..., _QNTOK],
+                 qq[..., _QSRV], zQ + minvr[:, None], qq[..., _QNCTX], qqd,
+                 qfs, qq[..., _QENT], qq[..., _QFLG] | 1, qq[..., _QSLC]],
+                axis=-1), qtail], axis=-1)
+        else:
+            brow = jnp.stack([qq[..., _QROW], qq[..., _QRID],
+                              qq[..., _QNTOK], zQ, zQ + minvr[:, None], zQ,
+                              delay, zQ + t, qq[..., _QENT], zQ + 1, zQ],
+                             axis=-1)
         pool = pool.at[gi[:, None], bpos].set(brow, mode="drop")
         n_byp = jnp.sum(bypass, axis=1, dtype=jnp.int32)
         pc = pc + n_byp
@@ -243,8 +383,11 @@ def _tick_core(G, L, QCAP, CAP, sfs, evcap, trace, state, arr, t, S, thr):
     pool = pool.at[gi[:, None], dpos, _PNCTX].add(one32, mode="drop")
     if trace:
         tr_pre = jnp.where(disp, last, -1)
-    last = jnp.where(sel[:, None], jnp.where(ch, crows[..., _PROW], -1),
-                     last)
+    # the pool's pick runs whenever FILTER leaves a lane free, an empty
+    # pick included; only a parked row can come back to be displaced
+    # against a stale pick, so only the stall variant keeps that rule
+    last = jnp.where((free > 0 if stall else sel)[:, None],
+                     jnp.where(ch, crows[..., _PROW], -1), last)
     nact = lc + k
 
     # ---- FILTER run + end of tick ------------------------------------
@@ -257,22 +400,59 @@ def _tick_core(G, L, QCAP, CAP, sfs, evcap, trace, state, arr, t, S, thr):
         fkey = jnp.where(done_f, gi[:, None] * (2 * L) + il[None, :],
                          _IMAX)
         zL = jnp.zeros((G, L), jnp.int32)
-        fev = jnp.stack([fkey, lanes[..., _LROW], lanes[..., _LSRV], zL,
-                         lanes[..., _LQD], lanes[..., _LFS],
-                         lanes[..., _LQE], zL, zL + 2,
-                         lanes[..., _LSLC]], axis=-1)
-        drow = jnp.stack([lanes[..., _LROW], lanes[..., _LRID],
-                          lanes[..., _LNTOK], lanes[..., _LSRV],
-                          zL + minvr[:, None], zL + 1, lanes[..., _LQD],
-                          lanes[..., _LFS], lanes[..., _LQE], zL + 3,
-                          lanes[..., _LSLC]], axis=-1)
+        if stall:
+            ltail = lanes[..., _NLS:]
+            lthr, ldur = _cur_event(ltail, ME)
+            park_f = lact & ~done_f & (lanes[..., _LSRV] - 1 >= lthr)
+            lnctx = lanes[..., _LNCTX]
+            fev = jnp.stack([fkey, lanes[..., _LROW], lanes[..., _LSRV],
+                             lnctx, lanes[..., _LQD], lanes[..., _LFS],
+                             lanes[..., _LQE], zL, zL + 2,
+                             lanes[..., _LSLC], ltail[..., 0]], axis=-1)
+            drow = jnp.concatenate([jnp.stack(
+                [lanes[..., _LROW], lanes[..., _LRID], lanes[..., _LNTOK],
+                 lanes[..., _LSRV], zL + minvr[:, None], lnctx + 1,
+                 lanes[..., _LQD], lanes[..., _LFS], lanes[..., _LQE],
+                 zL + 3, lanes[..., _LSLC]], axis=-1), ltail], axis=-1)
+            # park: a slice that ran out this tick demotes first (one
+            # switch), then the stall parks it (another)
+            srow_l = jnp.concatenate([jnp.stack(
+                [lanes[..., _LROW], lanes[..., _LRID], lanes[..., _LNTOK],
+                 lanes[..., _LSRV], jnp.where(exp_f, minvr[:, None], 0),
+                 lnctx + 1 + exp_f.astype(jnp.int32), lanes[..., _LQD],
+                 lanes[..., _LFS], lanes[..., _LQE],
+                 jnp.where(exp_f, 3, 2),
+                 lanes[..., _LSLC] if aware else zL, t + 1 + ldur], axis=-1),
+                ltail.at[..., 0].add(1)], axis=-1)
+        else:
+            fev = jnp.stack([fkey, lanes[..., _LROW], lanes[..., _LSRV], zL,
+                             lanes[..., _LQD], lanes[..., _LFS],
+                             lanes[..., _LQE], zL, zL + 2,
+                             lanes[..., _LSLC]], axis=-1)
+            drow = jnp.stack([lanes[..., _LROW], lanes[..., _LRID],
+                              lanes[..., _LNTOK], lanes[..., _LSRV],
+                              zL + minvr[:, None], zL + 1, lanes[..., _LQD],
+                              lanes[..., _LFS], lanes[..., _LQE], zL + 3,
+                              lanes[..., _LSLC]], axis=-1)
         if trace:
             tr_dem = jnp.where(exp_f, lanes[..., _LROW], -1)
 
     # ---- pool compaction: drop CFS finishes, append demotes ----------
     fin_c = ch & (srv2 >= crows[..., _PNTOK] + 1)
+    if stall:
+        ctail = crows[..., _NP:]
+        cthr, cdur = _cur_event(ctail, ME)
+        park_c = ch & ~fin_c & (srv2 - 1 >= cthr)
+        srow_c = jnp.concatenate([jnp.stack(
+            [crows[..., _PROW], crows[..., _PRID], crows[..., _PNTOK], srv2,
+             vr2, crows[..., _PNCTX] + 1, qd2, fs2, crows[..., _PQE],
+             crows[..., _PFLG], crows[..., _PSLC], t + 1 + cdur], axis=-1),
+            ctail.at[..., 0].add(1)], axis=-1)
+        left_c = fin_c | park_c
+    else:
+        left_c = fin_c
     finm = jnp.zeros((G, CAP), bool).at[
-        gi[:, None], jnp.where(fin_c, cpos, CAP)].set(True, mode="drop")
+        gi[:, None], jnp.where(left_c, cpos, CAP)].set(True, mode="drop")
     surv = pvalid & ~finm
     # stable compaction as a cumsum scatter (survivors keep their order;
     # dropped/tail slots zero out) — XLA:CPU sorts are comparator loops,
@@ -283,23 +463,40 @@ def _tick_core(G, L, QCAP, CAP, sfs, evcap, trace, state, arr, t, S, thr):
         pool, mode="drop")
     pc = jnp.sum(surv, axis=1, dtype=jnp.int32)
     if sfs:
-        dcum = jnp.cumsum(exp_f, axis=1, dtype=jnp.int32) - exp_f
-        dpos2 = jnp.where(exp_f, pc[:, None] + dcum, CAP)
+        dem = exp_f & ~park_f if stall else exp_f
+        dcum = jnp.cumsum(dem, axis=1, dtype=jnp.int32) - dem
+        dpos2 = jnp.where(dem, pc[:, None] + dcum, CAP)
         pool = pool.at[gi[:, None], dpos2].set(drow, mode="drop")
-        pc = pc + jnp.sum(exp_f, axis=1, dtype=jnp.int32)
+        pc = pc + jnp.sum(dem, axis=1, dtype=jnp.int32)
         # stable lane compaction, same cumsum-scatter trick
         lkeep = lact & ~(done_f | exp_f)
+        if stall:
+            lkeep = lkeep & ~park_f
         ldest = jnp.where(
             lkeep, jnp.cumsum(lkeep, axis=1, dtype=jnp.int32) - 1, L)
         lanes = jnp.zeros_like(lanes).at[gi[:, None], ldest].set(
             lanes, mode="drop")
         lc = jnp.sum(lkeep, axis=1, dtype=jnp.int32)
+    if stall:
+        # parked rows join the stalled region, lanes before the pool
+        if sfs:
+            prk = jnp.concatenate([park_f, park_c], axis=1)
+            srows = jnp.concatenate([srow_l, srow_c], axis=1)
+        else:
+            prk, srows = park_c, srow_c
+        pk = jnp.cumsum(prk, axis=1, dtype=jnp.int32) - prk
+        spos = jnp.where(prk, sc[:, None] + pk, SCAP)
+        sv = sv.at[gi[:, None], spos].set(srows, mode="drop")
+        n_park = jnp.sum(prk, axis=1, dtype=jnp.int32)
+        sc = sc + n_park
 
     # ---- monotone min_vruntime collapse ------------------------------
     pvalid2 = ic[None, :] < pc[:, None]
     m = jnp.where(pvalid2, pool[..., _PVR], _IMAX).min(axis=1)
     last_slot = jnp.maximum(k - 1, 0)
-    lastfin = jnp.take_along_axis(fin_c, last_slot[:, None], 1)[:, 0] & sel
+    # the last pick's own charge still saw it in the pool, whether it
+    # then finished or parked
+    lastfin = jnp.take_along_axis(left_c, last_slot[:, None], 1)[:, 0] & sel
     lastvr = jnp.take_along_axis(vr2, last_slot[:, None], 1)[:, 0]
     m = jnp.where(lastfin, jnp.minimum(m, lastvr), m)
     minvr = jnp.where(sel & (m < _IMAX), jnp.maximum(minvr, m), minvr)
@@ -307,61 +504,82 @@ def _tick_core(G, L, QCAP, CAP, sfs, evcap, trace, state, arr, t, S, thr):
     # ---- completion events, key-sorted to replay order ---------------
     ckey = jnp.where(fin_c, gi[:, None] * (2 * L) + L + il[None, :],
                      _IMAX)
-    cev = jnp.stack([ckey, crows[..., _PROW], srv2, crows[..., _PNCTX],
-                     qd2, fs2, crows[..., _PQE], vr2, crows[..., _PFLG],
-                     crows[..., _PSLC]], axis=-1)
+    cev = [ckey, crows[..., _PROW], srv2, crows[..., _PNCTX], qd2, fs2,
+           crows[..., _PQE], vr2, crows[..., _PFLG], crows[..., _PSLC]]
+    if stall:
+        cev.append(ctail[..., 0])
+    cev = jnp.stack(cev, axis=-1)
     # interleaving per engine (FILTER lanes, then CFS ranks) makes the
     # flattened grid already ascending in event key — compacting the
     # valid rows with a cumsum scatter replaces the argsort, and rows
     # past ``evcap`` fall off exactly like the old truncation
     grid = jnp.concatenate([fev, cev], axis=1) if sfs else cev
-    ev = grid.reshape(-1, _NE)
+    NE = _NES if stall else _NE
+    ev = grid.reshape(-1, NE)
     evalid = ev[:, _EKEY] < _IMAX
     n_ev = jnp.sum(evalid, dtype=jnp.int32)
     edest = jnp.where(evalid, jnp.cumsum(evalid, dtype=jnp.int32) - 1,
                       ev.shape[0])
-    ev = jnp.zeros((evcap, _NE), jnp.int32).at[edest].set(ev, mode="drop")
+    ev = jnp.zeros((evcap, NE), jnp.int32).at[edest].set(ev, mode="drop")
 
     # ---- distance to the next completion/expiry (event skip) ---------
     if sfs:
         lact2 = il[None, :] < lc[:, None]
-        lnext = jnp.where(
-            lact2,
-            jnp.minimum(lanes[..., _LNTOK] + 1 - lanes[..., _LSRV],
-                        lanes[..., _LSLC]), _IMAX).min(axis=1)
+        lnext = jnp.minimum(lanes[..., _LNTOK] + 1 - lanes[..., _LSRV],
+                            lanes[..., _LSLC])
+        if stall:
+            lnext = jnp.minimum(lnext, _park_dist(
+                _cur_event(lanes[..., _NLS:], ME)[0], lanes[..., _LSRV]))
+        lnext = jnp.where(lact2, lnext, _IMAX).min(axis=1)
         free2 = L - lc
     else:
         lnext = jnp.full(G, _IMAX, jnp.int32)
         free2 = jnp.full(G, L, jnp.int32)
     runnable = (free2 > 0) & (pc <= free2) & (pc > 0)
-    pnext = jnp.where(runnable[:, None] & pvalid2,
-                      pool[..., _PNTOK] + 1 - pool[..., _PSRV],
-                      _IMAX).min(axis=1)
+    prun = runnable[:, None] & pvalid2
+    pdist = pool[..., _PNTOK] + 1 - pool[..., _PSRV]
+    if stall:
+        pdist = jnp.minimum(pdist, _park_dist(
+            _cur_event(pool[..., _NP:], ME)[0], pool[..., _PSRV]))
+    pnext = jnp.where(prun, pdist, _IMAX).min(axis=1)
     min_next = jnp.minimum(lnext, pnext).min()
 
     state = dict(q=q, qh=qh, qn=qn, lanes=lanes, lc=lc, pool=pool, pc=pc,
                  minvr=minvr, last=last)
+    mirrors = [qn, lc, pc, nact, n_byp]
+    if stall:
+        state.update(stl=sv, sc=sc)
+        svalid2 = iS[None, :] < sc[:, None]
+        wake = jnp.where(svalid2, sv[..., _SWAKE], _IMAX)
+        wmin = wake.min(axis=1)
+        # a wake is an event: the gap advance stops at the earliest
+        min_next = jnp.minimum(min_next, wmin.min() - t)
+        mirrors += [sc, n_park, n_wake, qh, wmin,
+                    jnp.sum(svalid2 & (wake == wmin[:, None]), axis=1,
+                            dtype=jnp.int32)]
     out = {"events": ev,
            "scal": jnp.stack([n_ev, min_next]),
-           "mirrors": jnp.stack([qn, lc, pc, nact, n_byp])}
+           "mirrors": jnp.stack(mirrors)}
     if trace:
         out["trace_pre"] = tr_pre
         if sfs:
             out["trace_adm"] = tr_adm
             out["trace_byp"] = tr_byp
             out["trace_dem"] = tr_dem
+        if stall:
+            out["trace_park"] = jnp.where(prk, srows[..., _PROW], -1)
     return state, out
 
 
-def _advance_core(G, L, CAP, sfs, state, g, t0):
+def _advance_core(G, L, CAP, sfs, state, g, t0, stall=False):
     """Collapse ``g`` event-free ticks (valid only when the host proved
-    no fill, no finish, no expiry and no rotation can occur): active
-    lanes serve and burn slice for ``g`` ticks; pools that fit their
-    free lanes run whole for ``g`` ticks (first pick at ``t0`` settles
-    first-start accounting); ``min_vruntime`` telescopes to a max
-    against the final pool minimum; ``last`` becomes the pool itself,
-    so no displacement is ever recorded — the same no-op the per-tick
-    path would compute."""
+    no fill, no finish, no expiry, no rotation, no stall and no wake can
+    occur): active lanes serve and burn slice for ``g`` ticks; pools
+    that fit their free lanes run whole for ``g`` ticks (first pick at
+    ``t0`` settles first-start accounting); ``min_vruntime`` telescopes
+    to a max against the final pool minimum; ``last`` becomes the pool
+    itself, so no displacement is ever recorded — the same no-op the
+    per-tick path would compute.  The stalled region waits unchanged."""
     import jax.numpy as jnp
 
     q, qh, qn, lanes, lc, pool, pc, minvr, last = (state[k]
@@ -388,38 +606,51 @@ def _advance_core(G, L, CAP, sfs, state, g, t0):
     m = jnp.where(run, pool[..., _PVR], _IMAX).min(axis=1)
     minvr = jnp.where(run_eng & (m < _IMAX), jnp.maximum(minvr, m), minvr)
     rows_pad = jnp.where(pvalid, pool[..., _PROW], -1)[:, :L]
-    last = jnp.where(run_eng[:, None], rows_pad, last)
-    return dict(q=q, qh=qh, qn=qn, lanes=lanes, lc=lc, pool=pool, pc=pc,
-                minvr=minvr, last=last)
+    # as in the per-tick step: with stalls an empty pick resets it too
+    last = jnp.where((free > 0 if stall else run_eng)[:, None], rows_pad,
+                     last)
+    out = dict(q=q, qh=qh, qn=qn, lanes=lanes, lc=lc, pool=pool, pc=pc,
+               minvr=minvr, last=last)
+    if stall:
+        out.update({k: state[k] for k in _STALL_KEYS})
+    return out
 
 
 @lru_cache(maxsize=None)
-def _build_fns(G, L, QCAP, CAP, sfs, trace=False):
-    """Jitted (step, scan, advance) for one group shape.  Cached
-    module-wide so repeated growth and multiple same-shape groups reuse
+def _build_fns(G, L, QCAP, CAP, sfs, trace=False, stl=None):
+    """Jitted (step, scan, advance) for one group shape (and stall
+    config ``stl``, see :func:`_tick_core`).  Cached module-wide so
+    repeated growth and multiple same-shape groups reuse
     compilations."""
     import jax
     import jax.numpy as jnp
 
     evfull = G * L * (2 if sfs else 1)
-    step = jax.jit(partial(_tick_core, G, L, QCAP, CAP, sfs, evfull,
-                           trace))
+    if stl is None:
+        step = jax.jit(partial(_tick_core, G, L, QCAP, CAP, sfs, evfull,
+                               trace))
+    else:
+        step = jax.jit(partial(_tick_core, G, L, QCAP, CAP, sfs, evfull,
+                               trace, stl=stl))
 
     evscan = _scan_evcap(G, L, sfs)
 
     def scan_fn(state, t0, S, thr):
-        arr0 = jnp.full((1, _NA), -1, jnp.int32)
+        arr0 = jnp.full((1, _NA + _tail(stl)), -1, jnp.int32)
 
         def body(st, tt):
             # scan windows are only entered with telemetry traces off
             # (JaxCluster._fast_forward), so the body never traces
             return _tick_core(G, L, QCAP, CAP, sfs, evscan, False,
-                              st, arr0, tt, S, thr)
+                              st, arr0, tt, S, thr, stl)
 
         ts = t0 + jnp.arange(_SCAN_CHUNK, dtype=jnp.int32)
         return jax.lax.scan(body, state, ts)
 
-    adv = jax.jit(partial(_advance_core, G, L, CAP, sfs))
+    if stl is None:
+        adv = jax.jit(partial(_advance_core, G, L, CAP, sfs))
+    else:
+        adv = jax.jit(partial(_advance_core, G, L, CAP, sfs, stall=True))
     return step, jax.jit(scan_fn), adv
 
 
@@ -465,6 +696,7 @@ class _JaxGroup:
         of = sched_kw.get("overload_factor", 3.0)
         self.overload_factor = None if of is None else float(of)
         self.hinted_demotion = bool(sched_kw.get("hinted_demotion", False))
+        self.stall_aware = bool(sched_kw.get("stall_aware", True))
         init_S = (self.fixed_slice if self.fixed_slice is not None
                   else slice_init)
         self.S = np.full(G, init_S, np.int64)
@@ -486,6 +718,20 @@ class _JaxGroup:
         self.free_slots = np.full(G, n_slots, np.int64)
         self.outstanding = np.zeros(G, np.int64)
         self.min_next = 1
+        # stalls (§V-D): off until a request with stall events arrives
+        # (ME = the most events one of them has), then the stalled
+        # region and its mirrors: parked rows, their earliest wake tick
+        # and how many wake then, the tick the next step runs, and the
+        # parked rows that do not wake then (what the object engine
+        # leaves out of ``runnable_count`` when it routes that tick)
+        self.ME = 0
+        self.SCAP = 0
+        self._seq = 0                   # slot acquisitions handed out
+        self.parked = np.zeros(G, np.int64)
+        self.wmin = np.full(G, _IMAX, np.int64)
+        self.nwmin = np.zeros(G, np.int64)
+        self.t_next = 0
+        self.stalled = np.zeros(G, np.int64)
         # device regions
         self.QCAP = 64
         # fleet-scale runs reach pool depth ~2x lanes routinely; starting
@@ -506,10 +752,61 @@ class _JaxGroup:
         return {k: jnp.full(s, -1 if k == "last" else 0, jnp.int32)
                 for k, s in shapes.items()}
 
+    def _stl(self):
+        """The static stall config of the compiled programs, or None."""
+        return ((self.SCAP, self.ME, self.stall_aware) if self.ME
+                else None)
+
     def _compile(self):
         self._step_fn, self._scan_fn, self._adv_fn = _build_fns(
             self.G, self.lanes, self.QCAP, self.CAP, self.policy == "sfs",
-            self.trace is not None)
+            self.trace is not None, self._stl())
+
+    def _ensure_stalls(self, me: int):
+        """Make room for requests with up to ``me`` stall events: the
+        first time, switch the regions to the stall layout and add the
+        stalled region (sized like the pool); later, widen every stall
+        tail.  Pull, pad, push and re-jit, like :meth:`_grow`."""
+        import jax.numpy as jnp
+        if me <= self.ME:
+            return
+        prof = self.prof
+        if prof is not None:
+            prof.begin("jax_grow")
+        host = {k: np.asarray(v) for k, v in self._state.items()}
+
+        def tail(a, n0):
+            """``a`` with its stall tail widened from ``n0`` to ``me``
+            events (``n0 = None``: no tail yet), new thresholds _IMAX."""
+            add = (2 + 2 * me) if n0 is None else 2 * (me - n0)
+            pad = np.zeros(a.shape[:-1] + (add,), np.int32)
+            first = 2 if n0 is None else 0
+            pad[..., first::2] = _IMAX
+            return np.concatenate([a, pad], axis=-1)
+
+        if self.ME == 0:
+            G = self.G
+            q = host["q"]
+            hist = np.zeros(q.shape[:-1] + (_NQS - _NQ,), np.int32)
+            hist[..., _QFS - _NQ] = -1        # queued rows never ran
+            host["q"] = tail(np.concatenate([q, hist], axis=-1), None)
+            ln = host["lanes"]
+            host["lanes"] = tail(np.concatenate(
+                [ln, np.zeros(ln.shape[:-1] + (1,), np.int32)], axis=-1),
+                None)
+            host["pool"] = tail(host["pool"], None)
+            self.SCAP = self.CAP
+            host["stl"] = tail(np.zeros((G, self.SCAP, _NSS), np.int32),
+                               None)
+            host["sc"] = np.zeros(G, np.int32)
+        else:
+            for k in ("q", "lanes", "pool", "stl"):
+                host[k] = tail(host[k], self.ME)
+        self.ME = me
+        self._state = {k: jnp.asarray(v) for k, v in host.items()}
+        self._compile()
+        if prof is not None:
+            prof.end("jax_grow")
 
     def bind_telemetry(self, trace, prof):
         """Attach trace/profile collectors; tracing re-jits the step to
@@ -520,7 +817,7 @@ class _JaxGroup:
         if retrace:
             self._compile()
 
-    def _grow(self, *, qcap=None, cap=None):
+    def _grow(self, *, qcap=None, cap=None, scap=None):
         """Resize a device region: pull, pad (unrolling the queue ring
         to head 0), push back, re-jit against the new shapes."""
         import jax.numpy as jnp
@@ -529,7 +826,7 @@ class _JaxGroup:
             prof.begin("jax_grow")
         host = {k: np.asarray(v) for k, v in self._state.items()}
         if qcap is not None and qcap > self.QCAP:
-            q2 = np.zeros((self.G, qcap, _NQ), np.int32)
+            q2 = np.zeros((self.G, qcap, host["q"].shape[2]), np.int32)
             for j in range(self.G):
                 n = int(host["qn"][j])
                 idx = (int(self.qh[j]) + np.arange(n)) % self.QCAP
@@ -541,6 +838,9 @@ class _JaxGroup:
         if cap is not None and cap > self.CAP:
             host["pool"] = _grow_np(host["pool"], 1, cap)
             self.CAP = cap
+        if scap is not None and scap > self.SCAP:
+            host["stl"] = _grow_np(host["stl"], 1, scap)
+            self.SCAP = scap
         self._state = {k: jnp.asarray(v) for k, v in host.items()}
         self._compile()
         if prof is not None:
@@ -607,11 +907,9 @@ class _JaxGroup:
         self._batch.extend((j, kind, row, req.rid, req.n_tokens))
 
     def submit(self, j: int, req: Request, t: int):
-        if req.stall_events:
-            raise ValueError(
-                "the jax backend does not model stall events; pin this "
-                "server to the object engine and use engine='vector'")
         row = self.store.add(req)
+        if req.stall_events:
+            self._ensure_stalls(len(req.stall_events))
         self.outstanding[j] += 1
         if self.free_slots[j] > 0:
             self.free_slots[j] -= 1
@@ -626,6 +924,9 @@ class _JaxGroup:
         ``rows[k]``) to engine ``js[k]``, for each ``k`` in order: the
         same mirrors, pending deques, slice windows and arrival batch.
         Hinted demotion is not modelled (callers submit one by one)."""
+        st = self.store
+        if st.max_stalls > self.ME:
+            self._ensure_stalls(int(st.stall_n[rows].max()))
         cnt = np.bincount(js, minlength=self.G)
         self.outstanding += cnt
         # the first free_slots arrivals at an engine take a slot, in
@@ -685,20 +986,33 @@ class _JaxGroup:
         npl = np.bincount(bj[kc], minlength=G)
         # the mirrors already include this batch (classify is eager);
         # conservative pool headroom: every queued entry could bypass
-        # into the pool this tick, and every lane could demote
-        if int(self.qlen.max(initial=0)) > self.QCAP:
+        # into the pool this tick, and every lane could demote.  With
+        # stalls any resident can come back from the stalled region,
+        # inside a scan chunk too: the queue holds at most what is not
+        # in the pool, pool and stalled region at most every resident
+        qneed = self.qlen
+        pneed = self.cfs_count + self.qlen + L
+        if self.ME:
+            qneed = qneed + L + self.parked
+            pneed = pneed + self.parked
+        if int(qneed.max(initial=0)) > self.QCAP:
             want = self.QCAP
-            while int(self.qlen.max()) > want:
+            while int(qneed.max()) > want:
                 want *= 2
             self._grow(qcap=want)
-        if int((self.cfs_count + self.qlen + L).max(initial=0)) > self.CAP:
+        if int(pneed.max(initial=0)) > self.CAP:
             want = self.CAP
-            while int((self.cfs_count + self.qlen + L).max()) > want:
+            while int(pneed.max()) > want:
                 want *= 2
             self._grow(cap=want)
+        if self.ME and int(pneed.max(initial=0)) > self.SCAP:
+            want = self.SCAP
+            while int(pneed.max()) > want:
+                want *= 2
+            self._grow(scap=want)
         while len(b) > self.ACAP:
             self.ACAP *= 2
-        arr = np.full((self.ACAP, _NA), -1, np.int32)
+        arr = np.full((self.ACAP, _NA + _tail(self._stl())), -1, np.int32)
         if batch:
             # per-(engine, region) arrival ranks in batch order
             rank = _group_ranks(bj * 2 + kc)
@@ -708,6 +1022,8 @@ class _JaxGroup:
                            (self.qh[bj] + qbase[bj] + rank) % self.QCAP)
             arr[:len(b), :5] = b
             arr[:len(b), 5] = pos
+            if self.ME:
+                self._stall_tail(arr[:len(b)], b[:, 2])
         qn_in = self.qlen.copy()
         if prof is not None:
             prof.end("jax_prep")
@@ -724,7 +1040,7 @@ class _JaxGroup:
             prof.end("jax_sync")
         n_ev = int(scal[0])
         self.min_next = int(scal[1])
-        qn2, lc2, pc2, nact, nbyp = mir
+        qn2, lc2, pc2, nact, nbyp = mir[:5]
         n_ex = qn_in - qn2
         self.qh = (self.qh + n_ex) % self.QCAP
         self.qlen = qn2
@@ -735,6 +1051,8 @@ class _JaxGroup:
         self.overload_bypasses += nbyp
         if prof is not None:
             prof.end("jax_step")
+        if self.ME or prof is not None:
+            self._stall_sync(mir[5:], mir[6:8], t + 1)
         if self.trace is not None:
             self._emit_trace(out, t)
         if n_ev == 0:
@@ -750,6 +1068,47 @@ class _JaxGroup:
             prof.end("jax_events")
         return res
 
+    def _stall_tail(self, arr: np.ndarray, rows: np.ndarray):
+        """Fill the stall tail of a tick's arrival rows: no event
+        consumed, the next slot-acquisition numbers, and each request's
+        stall schedule from the store."""
+        n = len(rows)
+        arr[:, _NA] = 0
+        arr[:, _NA + 1] = self._seq + np.arange(n)
+        self._seq += n
+        arr[:, _NA + 2:] = self.store.stall_ev[rows, :2 * self.ME]
+
+    def _stall_sync(self, mir: np.ndarray, moves: np.ndarray, t_next: int):
+        """Host stall bookkeeping after a step or a scan chunk: the
+        stalled-region mirrors from ``mir`` (the last tick's ``[parked,
+        parks, wakes, qh, earliest wake, rows waking then]``) and the
+        park and wake counters from ``moves`` (``[parks, wakes]`` of
+        every tick stepped).  Profiled, a group that never stalled
+        spans nothing here and counts no park or wake."""
+        prof = self.prof
+        if prof is not None:
+            prof.begin("stall_sync")
+        parks = wakes = 0
+        if self.ME:
+            sc, _npk, _nwk, qh, wmin, nwmin = mir
+            self.parked = sc
+            self.qh = qh
+            self.wmin = wmin
+            self.nwmin = nwmin
+            self._restall(t_next)
+            parks, wakes = int(moves[0].sum()), int(moves[1].sum())
+        if prof is not None:
+            prof.count("stall_park", parks)
+            prof.count("stall_wake", wakes)
+            prof.end("stall_sync")
+
+    def _restall(self, t_next: int):
+        """Parked rows not waking at ``t_next``, the tick the next step
+        runs (all of them wake at or after it)."""
+        self.t_next = t_next
+        self.stalled = self.parked - np.where(self.wmin == t_next,
+                                              self.nwmin, 0)
+
     def _emit_trace(self, out, t: int):
         """Reconstruct the lifecycle events the object/vector schedulers
         emit inline from the device row masks (-1 = no event).  Order
@@ -760,7 +1119,10 @@ class _JaxGroup:
             return
         keys = ([("admit", "trace_adm"), ("bypass", "trace_byp"),
                  ("demote", "trace_dem")] if self.policy == "sfs" else [])
-        for kind, key in keys + [("preempt", "trace_pre")]:
+        keys.append(("preempt", "trace_pre"))
+        if self.ME:
+            keys.append(("preempt", "trace_park"))    # §V-D parks
+        for kind, key in keys:
             a = np.asarray(out[key])
             g, p = np.nonzero(a >= 0)
             if g.size:
@@ -788,6 +1150,8 @@ class _JaxGroup:
         st.slice_set[rows] = (ev[:, _EFLG] >> 1).astype(bool)
         st.slice_left[rows] = ev[:, _ESLC]
         st.finish[rows] = t + 1
+        if self.ME:
+            st.stall_idx[rows] = ev[:, _ESTI]
         np.add.at(self.free_slots, eng, 1)
         np.add.at(self.outstanding, eng, -1)
         if self.trace is not None:
@@ -825,10 +1189,20 @@ class _JaxGroup:
         pc = int(host["pc"][j])
         if pc:
             rows.extend(host["pool"][j, :pc, _PROW].tolist())
+        keys = ["q", "lanes", "pool", "qh", "qn", "lc", "pc"]
+        if self.ME:
+            sc = int(host["sc"][j])
+            if sc:                           # parked rows hold slots too
+                rows.extend(host["stl"][j, :sc, _PROW].tolist())
+            keys += _STALL_KEYS
+            self.parked[j] = 0
+            self.wmin[j] = _IMAX
+            self.nwmin[j] = 0
+            self.stalled[j] = 0
         evicted = [st.reqs[int(r)] for r in rows]
         evicted.extend(req for _row, req in self.pending[j])
         self.pending[j].clear()
-        for k in ("q", "lanes", "pool", "qh", "qn", "lc", "pc"):
+        for k in keys:
             host[k][j] = 0
         host["last"][j] = -1
         self._state = {k: jnp.asarray(v) for k, v in host.items()}
@@ -916,6 +1290,21 @@ class _JaxGroup:
                 host["pool"][j, pc - 1] = 0
                 host["pc"][j] = pc - 1
                 self.cfs_count[j] -= 1
+        sc = int(host["sc"][j]) if self.ME else 0
+        if row is None and sc:
+            sv = host["stl"][j]
+            hit = np.nonzero(sv[:sc, _PRID] == rid)[0]
+            if hit.size:
+                p = int(hit[0])
+                row = int(sv[p, _PROW])
+                sv[p:sc - 1] = sv[p + 1:sc]
+                sv[sc - 1] = 0
+                host["sc"][j] = sc - 1
+                self.parked[j] -= 1
+                wake = sv[:sc - 1, _SWAKE]
+                self.wmin[j] = wake.min(initial=_IMAX)
+                self.nwmin[j] = int((wake == self.wmin[j]).sum())
+                self._restall(self.t_next)
         if row is None:
             return None
         lr = host["last"][j]
@@ -950,6 +1339,8 @@ class _JaxGroup:
     def advance(self, g: int, t0: int):
         self._state = self._adv_fn(self._state, np.int32(g), np.int32(t0))
         self.min_next -= g
+        if self.ME:
+            self._restall(t0 + g)
         self.lane_busy_ticks += g * self.gap_active_counts()
 
     def scan(self, t0: int):
@@ -983,7 +1374,7 @@ class _JaxGroup:
             per_tick.append(
                 self._process_events(events[i, :n].astype(np.int64),
                                      t0 + i) if n else [])
-        qn2, lc2, pc2, nact, _nbyp = mir[-1]
+        qn2, lc2, pc2, nact, _nbyp = mir[-1, :5]
         self.qh = (self.qh + (self.qlen - qn2)) % self.QCAP
         self.qlen = qn2
         self.filter_count = lc2
@@ -991,6 +1382,9 @@ class _JaxGroup:
         self.n_active = nact
         self.lane_busy_ticks += mir[:, 3].sum(axis=0)
         self.overload_bypasses += mir[:, 4].sum(axis=0)
+        if self.ME or self.prof is not None:
+            self._stall_sync(mir[-1, 5:], mir[:, 6:8].swapaxes(0, 1),
+                             t0 + _SCAN_CHUNK)
         return per_tick, mir[:, 3]
 
 
@@ -1027,7 +1421,7 @@ class JaxServerView(ServerView):
     def capacity(self) -> int:
         g, j = self.group, self.j
         slots = int(g.free_slots[j]) - int(g.pending_len[j])
-        lanes = g.lanes - int(g.outstanding[j])
+        lanes = g.lanes - int(g.outstanding[j]) + int(g.stalled[j])
         return max(0, min(slots, lanes))
 
 
@@ -1084,6 +1478,8 @@ class _JaxColumns(ServerStateColumns):
             ff = g.lanes - min(g.lanes, fair)
         self.queue_len[i] = ql
         self.filter_free[i] = ff if ff > 0 else 0
+        if g.ME:
+            out -= g.stalled[j]             # parked: not runnable
         cap = min(g.free_slots[j] - g.pending_len[j], g.lanes - out)
         self.capacity[i] = cap if cap > 0 else 0
 
@@ -1101,7 +1497,7 @@ class _JaxColumns(ServerStateColumns):
                     0, g.lanes - np.minimum(g.lanes, g.cfs_count))
             self.capacity[m] = np.maximum(
                 0, np.minimum(g.free_slots - g.pending_len,
-                              g.lanes - g.outstanding))
+                              g.lanes - g.outstanding + g.stalled))
 
 
 class JaxCluster(ClusterFrontend):
@@ -1168,8 +1564,7 @@ class JaxCluster(ClusterFrontend):
         if (not arrivals or pol.active is not None
                 or not isinstance(pol, (HashDispatch, SFSAwareDispatch))
                 or self._warm is not None or self._trace is not None
-                or self._watchdog is not None or self._hinted
-                or any(r.stall_events for r in arrivals)):
+                or self._watchdog is not None or self._hinted):
             return super()._route_tick(arrivals)
         prof = self._prof
         if prof is not None:

@@ -57,6 +57,10 @@ _SFS_KW = {"slice_ticks", "adaptive_window", "slice_init",
 VECTOR_POLICIES = ("sfs", "cfs")
 
 
+# stall-schedule padding: a threshold no request reaches
+_NO_STALL = 2 ** 31 - 1
+
+
 def _grow(a: np.ndarray, cols: int, fill) -> np.ndarray:
     pad = np.full(a.shape[:-1] + (cols - a.shape[-1],), fill, a.dtype)
     return np.concatenate([a, pad], axis=-1)
@@ -89,6 +93,12 @@ class _RequestStore:
         self.in_cfs = np.zeros(cap, bool)
         self.pool_pos = np.full(cap, -1, np.int64)
         self.mark = np.zeros(cap, bool)          # reusable scratch mask
+        # stall schedules (§V-D), allocated at the first request that
+        # has one: ``stall_ev[row]`` = (threshold, ticks) pairs padded
+        # with (_NO_STALL, 0), ``stall_n[row]`` their count,
+        # ``stall_idx[row]`` the events consumed (written at completion)
+        self.max_stalls = 0
+        self.stall_n = self.stall_idx = self.stall_ev = None
 
     _ARRAYS = ("rid", "n_tokens", "tokens_done", "served", "prefill_done",
                "slice_left", "slice_set", "vruntime", "n_ctx", "demoted",
@@ -104,6 +114,39 @@ class _RequestStore:
             fill = (-1 if name in ("first_start", "finish", "pool_pos")
                     else 0)
             setattr(self, name, _grow(getattr(self, name), size, fill))
+        if self.stall_n is not None:
+            self.stall_n = _grow(self.stall_n, size, 0)
+            self.stall_idx = _grow(self.stall_idx, size, 0)
+            self.stall_ev = self._stall_pad(self.stall_ev, size,
+                                            self.stall_ev.shape[1])
+
+    @staticmethod
+    def _stall_pad(ev, rows: int, cols: int) -> np.ndarray:
+        """``ev`` grown to ``rows`` x ``cols``, new pairs (_NO_STALL, 0)."""
+        out = np.zeros((rows, cols), np.int64)
+        out[:, 0::2] = _NO_STALL
+        if ev is not None:
+            out[:ev.shape[0], :ev.shape[1]] = ev
+        return out
+
+    def _add_stalls(self, r0: int, reqs: Sequence[Request]):
+        """Record the stall schedules of ``reqs`` (rows ``r0`` on)."""
+        evs = [r.stall_events for r in reqs]
+        m = max(len(e) for e in evs)
+        size = self.rid.size
+        if self.stall_n is None:
+            self.stall_n = np.zeros(size, np.int64)
+            self.stall_idx = np.zeros(size, np.int64)
+        if m > self.max_stalls:
+            self.stall_ev = self._stall_pad(self.stall_ev, size, 2 * m)
+            self.max_stalls = m
+        r1 = r0 + len(reqs)
+        self.stall_n[r0:r1] = [len(e) for e in evs]
+        for k in range(m):
+            self.stall_ev[r0:r1, 2 * k] = [
+                e[k][0] if len(e) > k else _NO_STALL for e in evs]
+            self.stall_ev[r0:r1, 2 * k + 1] = [
+                e[k][1] if len(e) > k else 0 for e in evs]
 
     def add(self, req: Request) -> int:
         if self.n == self.rid.size:
@@ -113,6 +156,8 @@ class _RequestStore:
         self.reqs.append(req)
         self.rid[row] = req.rid
         self.n_tokens[row] = req.n_tokens
+        if req.stall_events:
+            self._add_stalls(row, [req])
         return row
 
     def add_many(self, reqs: Sequence[Request]) -> np.ndarray:
@@ -124,6 +169,8 @@ class _RequestStore:
         self.reqs.extend(reqs)
         self.rid[r0:r1] = [r.rid for r in reqs]
         self.n_tokens[r0:r1] = [r.n_tokens for r in reqs]
+        if any(r.stall_events for r in reqs):
+            self._add_stalls(r0, reqs)
         return np.arange(r0, r1)
 
     def write_back(self, row: int):
@@ -144,6 +191,8 @@ class _RequestStore:
         r.slice_left = (int(self.slice_left[row]) if self.slice_set[row]
                         else None)
         r.slot = None
+        if self.stall_idx is not None:
+            r.stall_idx = int(self.stall_idx[row])
         return r
 
     def write_back_many(self, rows: Sequence[int]) -> list:
@@ -180,6 +229,9 @@ class _RequestStore:
             r.slice_left = sl[k] if ss[k] else None
             r.slot = None
             out.append(r)
+        if self.stall_idx is not None:
+            for r, si in zip(out, self.stall_idx[idx].tolist()):
+                r.stall_idx = si
         return out
 
 

@@ -214,6 +214,46 @@ def test_trace_agreement_tick_vector_jax(n_engines):
     assert counts["admit"] > 0                  # FILTER actually engaged
 
 
+def _with_stalls(reqs, seed, p=0.7):
+    """``reqs`` with the paper's leading I/O on a share ``p``: one stall
+    event of 1-2 ticks after the first tick (Sec. VIII-B, at ~50 ms a
+    tick), and a later one of 1-4 ticks on every fifth of them."""
+    rng = np.random.default_rng(seed)
+    for r in reqs:
+        if rng.random() < p:
+            ev = ((0, int(rng.integers(1, 3))),)
+            if rng.random() < 0.2:
+                ev += ((int(rng.integers(1, 20)), int(rng.integers(1, 5))),)
+            r.stall_events = ev
+    return reqs
+
+
+@pytest.mark.parametrize("n_engines", [4, 64])
+def test_trace_agreement_tick_jax_with_stalls(n_engines):
+    """Stall events on the jax backend (the vector backend refuses
+    them): the same canonical lifecycle stream and fingerprint as the
+    tick engine, parks among the preempts, with the history predictor's
+    feedback loop in the routing."""
+    servers = tuple(ServerSpec(cores=4) for _ in range(n_engines))
+    n = 100 * n_engines if n_engines < 64 else 1600
+    canon, fps, counts = {}, {}, None
+    for engine in ("tick", "jax"):
+        reqs = _with_stalls(tick_workload(n=n, lanes=4 * n_engines,
+                                          seed=29), 31)
+        tel = Telemetry(trace=True)
+        res = run_experiment(ExperimentSpec(
+            engine=engine, servers=servers, dispatch="sfs-aware",
+            predictor="history"), reqs, max_ticks=2_000_000,
+            telemetry=tel)
+        canon[engine] = tel.trace.canonical()
+        fps[engine] = (_full_fingerprint(res.raw), res.dispatch_counts,
+                       [r.stall_idx for r in res.raw])
+        counts = counts or tel.trace.counts()
+    assert canon["tick"] == canon["jax"]
+    assert fps["tick"] == fps["jax"]
+    assert counts["preempt"] > 0.6 * n      # at least every park
+
+
 def test_trace_agreement_covers_demote_and_preempt():
     """Contention scenario (high load, hinted demotion) so the rarer
     demote/preempt/bypass kinds are exercised — still equal-trace."""

@@ -1,7 +1,8 @@
 """JAX cluster backend edges: empty-tick and event-skip fast paths,
 ``lax.scan`` chunking (commit, overflow, cooldown), device-region
-growth, the unsupported-feature gates, and the group_pick kernel
-parity promises (Pallas interpret mode vs both jnp implementations).
+growth, the unsupported-feature gates, stall events against the tick
+engine, and the group_pick kernel parity promises (Pallas interpret
+mode vs both jnp implementations).
 
 Bit-exactness against the numpy vector backend across dispatch
 policies and fleet sizes is asserted in ``tests/test_agreement.py``;
@@ -10,7 +11,7 @@ import numpy as np
 import pytest
 
 import repro.serving.jax_cluster as jc_mod
-from repro.core.spec import ServerSpec
+from repro.core.spec import ExperimentSpec, ServerSpec, run_experiment
 from repro.core.telemetry import Telemetry
 from repro.serving import ClusterConfig, Request, VectorCluster
 from repro.serving.cluster import ClusterFrontend
@@ -64,14 +65,6 @@ def test_object_pinned_server_raises():
     # jitted step, so object-engine riders go to engine="vector"
     with pytest.raises(ValueError, match="jax backend"):
         JaxCluster([ServerSpec(cores=4, engine="object")], ClusterConfig())
-
-
-def test_stall_events_rejected_at_submit():
-    jc = JaxCluster([ServerSpec(cores=2)], ClusterConfig())
-    req = Request(rid=0, arrival=0, prompt_len=4, n_tokens=5,
-                  stall_events=((2, 3),))
-    with pytest.raises(ValueError, match="stall events"):
-        jc.tick([req])
 
 
 # ---------------------------------------------------------------------------
@@ -448,6 +441,202 @@ def test_columns_delivery_model_matches_pull(scheduler):
                 cols.fair_load)], (scheduler, k, i)
     g = c.groups[0]
     assert g.pending_len.max() > 0 and (g.free_slots == 0).all()
+
+
+# ---------------------------------------------------------------------------
+# Stall events (§V-D parking): jax against the tick engine
+# ---------------------------------------------------------------------------
+
+
+def _stall_workload(n, lanes, seed, load=1.0, p=0.6, thr=(0, 3), dur=(1, 4),
+                    events=1, gap=None, n_tok=None):
+    """Bimodal demands with Poisson arrivals at ``load`` over ``lanes``
+    (or one arrival every ``gap`` ticks); a share ``p`` of the requests
+    carries ``events`` stall events, thresholds drawn on ``thr`` (each
+    after the last) and durations on ``dur``."""
+    rng = np.random.default_rng(seed)
+    svc = (np.full(n, n_tok) if n_tok is not None else
+           np.where(rng.random(n) < 0.8, rng.integers(2, 8, n),
+                    rng.integers(30, 80, n)))
+    if gap is None:
+        iats = rng.exponential(1.0, n)
+        arr = np.cumsum(iats * svc.sum() / (load * lanes) / iats.sum())
+    else:
+        arr = np.arange(n) * gap
+    out = []
+    for i in range(n):
+        ev, at = [], 0
+        if rng.random() < p:
+            for _ in range(events):
+                at += int(rng.integers(*thr))
+                ev.append((at, int(rng.integers(*dur))))
+        out.append(Request(rid=i, arrival=int(arr[i]), prompt_len=4,
+                           n_tokens=int(svc[i]), eta_hint=int(svc[i]) + 1,
+                           stall_events=tuple(ev)))
+    return out
+
+
+def _stall_fingerprint(reqs):
+    return fingerprint(reqs) + [r.stall_idx for r in reqs]
+
+
+class _StallWitness:
+    """What the jax groups did with stalls during a run: parks from
+    FILTER lanes and from the pool, wakes inside committed scan chunks,
+    gap advances that end on a wake tick, steps with both a pending
+    deque and parked rows, and every program built."""
+
+    def __init__(self, monkeypatch):
+        self.seen = dict(filter_park=0, pool_park=0, scan_wakes=0,
+                         gap_to_wake=0, pending_and_parked=0)
+        self.builds = []
+        G = jc_mod._JaxGroup
+        seen, builds = self.seen, self.builds
+        emit, commit = G._emit_trace, G.commit_scan
+        advance, step = G.advance, G.step_tick
+        build = jc_mod._build_fns
+
+        def _emit_trace(g, out, t):
+            if "trace_park" in out:
+                # sfs: FILTER lanes, then the pool's picks; cfs: picks
+                hit = np.asarray(out["trace_park"]) >= 0
+                lanes = g.lanes if g.policy == "sfs" else 0
+                seen["filter_park"] += int(hit[:, :lanes].sum())
+                seen["pool_park"] += int(hit[:, lanes:].sum())
+            return emit(g, out, t)
+
+        def commit_scan(g, t0, payload):
+            if g.ME:
+                seen["scan_wakes"] += int(payload[2][:, 7].sum())
+            return commit(g, t0, payload)
+
+        def advance_(g, n, t0):
+            if g.ME and g.parked.any() and g.wmin.min() == t0 + n:
+                seen["gap_to_wake"] += 1
+            return advance(g, n, t0)
+
+        def step_tick(g, t):
+            res = step(g, t)
+            if g.pending_len.any() and g.parked.any():
+                seen["pending_and_parked"] += 1
+            return res
+
+        def build_fns(*a):
+            builds.append(a)
+            return build(*a)
+
+        monkeypatch.setattr(G, "_emit_trace", _emit_trace)
+        monkeypatch.setattr(G, "commit_scan", commit_scan)
+        monkeypatch.setattr(G, "advance", advance_)
+        monkeypatch.setattr(G, "step_tick", step_tick)
+        monkeypatch.setattr(jc_mod, "_build_fns", build_fns)
+
+
+def _parked_then(trace, kind):
+    """Requests that a ``preempt`` (park) precedes a later ``kind``."""
+    first = {}
+    hits = set()
+    for t, k, rid, _srv, _aux in trace:
+        if k == "preempt":
+            first.setdefault(rid, t)
+        elif k == kind and rid in first and first[rid] < t:
+            hits.add(rid)
+    return hits
+
+
+_SFS2 = ServerSpec(cores=2)
+STALL_CASES = {
+    # (servers, dispatch, workload, witness(seen, results, trace))
+    "filter-lane-sfs-aware": (
+        (_SFS2,) * 4, "sfs-aware", dict(n=240, lanes=8, seed=3),
+        lambda s, r, tr: s["filter_park"] > 0),
+    "pool-hash": (
+        (ServerSpec(cores=2, scheduler="sfs:slice=3"),) * 3, "hash",
+        dict(n=200, lanes=6, seed=4, thr=(4, 40)),
+        lambda s, r, tr: s["pool_park"] > 0),
+    "wake-bypass": (
+        (ServerSpec(cores=2, scheduler="sfs:slice=4,overload_factor=0.5"),)
+        * 2, "hash", dict(n=200, lanes=4, seed=5, load=1.3),
+        lambda s, r, tr: len(_parked_then(tr, "bypass")) > 0),
+    "slots-full": (
+        (ServerSpec(cores=2, slots=3),) * 3, "sfs-aware",
+        dict(n=300, lanes=6, seed=6, load=1.4),
+        lambda s, r, tr: s["pending_and_parked"] > 0),
+    "wake-in-scan": (
+        (_SFS2,) * 2, "least-outstanding",
+        dict(n=16, lanes=4, seed=7, gap=0, n_tok=90, p=1.0, thr=(20, 60),
+             dur=(2, 9)),
+        lambda s, r, tr: s["scan_wakes"] > 0),
+    "wake-ends-gap": (
+        (_SFS2,) * 3, "sfs-aware",
+        dict(n=24, lanes=6, seed=8, gap=120, p=0.8, dur=(20, 60)),
+        lambda s, r, tr: s["gap_to_wake"] > 0),
+    "two-events-sfs-aware": (
+        (_SFS2,) * 4, "sfs-aware", dict(n=240, lanes=8, seed=9, events=2),
+        lambda s, r, tr: any(x.stall_idx == 2 and x.n_ctx >= 2 for x in r)),
+    "two-events-cfs-hash": (
+        (ServerSpec(cores=2, scheduler="cfs"),) * 3, "hash",
+        dict(n=200, lanes=6, seed=10, events=2, load=1.2),
+        lambda s, r, tr: s["pool_park"] > 0
+        and any(x.stall_idx == 2 for x in r)),
+    "slice-burns-while-stalled": (
+        (ServerSpec(cores=2, scheduler="sfs:stall_aware=False"),) * 3,
+        "sfs-aware", dict(n=240, lanes=6, seed=11, load=1.2),
+        lambda s, r, tr: s["filter_park"] > 0),
+    # pull reads capacity: parked requests are not runnable, unless
+    # they wake at the tick being routed
+    "pull-capacity": (
+        (_SFS2,) * 4, "pull", dict(n=240, lanes=8, seed=13, load=1.2),
+        lambda s, r, tr: s["filter_park"] > 0),
+    # two pool picks park, the pool runs empty (an empty pick clears
+    # what it ran last), both wake together onto one lane: the one
+    # passed over was not displaced
+    "empty-pick-while-parked": (
+        (ServerSpec(cores=1, scheduler="cfs"),), "hash",
+        lambda: [Request(rid=i, arrival=0, prompt_len=4, n_tokens=5,
+                         stall_events=((0, 3 - i),)) for i in range(2)],
+        lambda s, r, tr: s["pool_park"] == 2),
+    "never-stalls": (
+        (_SFS2,) * 4, "sfs-aware", dict(n=240, lanes=8, seed=12, p=0.0),
+        lambda s, r, tr: s == dict(filter_park=0, pool_park=0,
+                                   scan_wakes=0, gap_to_wake=0,
+                                   pending_and_parked=0)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(STALL_CASES))
+def test_stalls_match_tick_engine(case, monkeypatch):
+    """engine="jax" with stall events == engine="tick", field for field
+    (stall_idx too), with the same dispatch counts; traced (the
+    per-tick path), the same lifecycle events, a park a ``preempt``;
+    untraced, through the scan and gap fast paths, the same results
+    again.  Each case's witness shows its path was taken.  A fleet that
+    never stalls keeps the stall region off: its groups build only the
+    programs without it."""
+    servers, dispatch, wl, witness = STALL_CASES[case]
+    workload = wl if callable(wl) else (lambda: _stall_workload(**wl))
+    spec = ExperimentSpec(engine="tick", servers=servers, dispatch=dispatch,
+                          predictor="oracle")
+    tick_tel = Telemetry(trace=True)
+    want = run_experiment(spec, workload(), max_ticks=200_000,
+                          telemetry=tick_tel)
+    spy = _StallWitness(monkeypatch)
+    jax_spec = ExperimentSpec(engine="jax", servers=servers,
+                              dispatch=dispatch, predictor="oracle")
+    tel = Telemetry(trace=True)
+    traced = run_experiment(jax_spec, workload(), max_ticks=200_000,
+                            telemetry=tel)
+    fast = run_experiment(jax_spec, workload(), max_ticks=200_000)
+    ref = _stall_fingerprint(want.raw)
+    assert _stall_fingerprint(traced.raw) == ref
+    assert _stall_fingerprint(fast.raw) == ref
+    assert traced.dispatch_counts == want.dispatch_counts
+    assert fast.dispatch_counts == want.dispatch_counts
+    assert tel.trace.canonical() == tick_tel.trace.canonical()
+    assert witness(spy.seen, want.raw, tick_tel.trace.canonical()), spy.seen
+    stalled = any(r.stall_events for r in workload())
+    configs = [b[6] for b in spy.builds]        # _build_fns(..., stl)
+    assert configs and any(c is not None for c in configs) == stalled
 
 
 # ---------------------------------------------------------------------------

@@ -278,7 +278,8 @@ def test_jax_profile_spans_every_phase_once_or_per_step():
 
 def test_spans_are_profiler_annotations_on_the_host_timeline(tmp_path):
     """Under a live jax profiler session every span is also a
-    ``repro.<phase>`` host event, nested as the spans nest."""
+    ``repro.<phase>`` host event, nested as the spans nest; a counter
+    (the stall parks and wakes) is none."""
     import jax
     run_experiment(_jax_fleet(600))                 # compile off the trace
     tel = Telemetry(profile=True)
@@ -296,13 +297,17 @@ def test_spans_are_profiler_annotations_on_the_host_timeline(tmp_path):
     counts = {}
     for _, _, n in spans:
         counts[n] = counts.get(n, 0) + 1
-    assert counts == {k: v[1] for k, v in tel.profile.phases.items()}
+    prof = tel.profile
+    assert prof.counters == {"stall_park", "stall_wake"}
+    assert counts == {k: v[1] for k, v in prof.phases.items()
+                      if k not in prof.counters}
 
-    def inside(inner, outer):
-        outs = [(s, e) for s, e, n in spans if n == outer]
+    def inside(inner, *outer):
+        outs = [(s, e) for s, e, n in spans if n in outer]
         return all(any(s0 <= s and e <= e0 for s0, e0 in outs)
                    for s, e, n in spans if n == inner)
     assert inside("jax_sync", "jax_step") and inside("jax_prep", "step")
+    assert inside("stall_sync", "step", "jax_commit")
     assert inside("jax_writeback", "result")
 
 

@@ -73,3 +73,30 @@ def test_fleet1024_group_step_compiles_with_pallas_pick(one_chip,
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes < 16 * 2**20
+
+
+@pytest.mark.parametrize("program", ["step", "scan"])
+def test_fleet1024_stall_programs_compile_with_pallas_pick(one_chip,
+                                                           monkeypatch,
+                                                           program):
+    """The stall variant of the group tick (the 1024 x 12 fleet of the
+    I/O cell, one stall event a request) compiles for the chip, per
+    tick and as a scan chunk."""
+    import repro.kernels.group_pick as group_pick
+    from repro.kernels.group_pick.kernel import pick_order_pallas
+    from repro.serving.jax_cluster import _build_fns, step_arg_specs
+    monkeypatch.setattr(group_pick, "pick_order",
+                        lambda vr, rid, kmax: pick_order_pallas(vr, rid,
+                                                                kmax))
+    G, L, QCAP, CAP, ACAP = 1024, 12, 64, 64, 1024
+    stl = (CAP, 1, True)
+    step, scan, _ = _build_fns.__wrapped__(G, L, QCAP, CAP, True, False,
+                                           stl)
+    state, arr, t, S, thr = step_arg_specs(G, L, QCAP, CAP, ACAP,
+                                           sharding=one_chip, stl=stl)
+    if program == "step":
+        compiled = step.lower(state, arr, t, S, thr).compile()
+    else:
+        compiled = scan.lower(state, t, S, thr).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().argument_size_in_bytes < 16 * 2**20
